@@ -17,9 +17,10 @@ is compiled and *timed* on the verification machine) in three sections:
 
 3. **Measured search** (``--measured``, also in ``--smoke``) — the
    paper's real measurement loop: ``fidelity="measured"`` wall-clocks
-   every unique candidate in spawn-context subprocess workers. Slowest
-   and most honest; tiny budget by design (the run-fn cache key
+   every unique candidate in this process, on the device JAX uses.
+   Slowest and most honest; tiny budget by design (the run-fn cache key
    collapses equivalent genomes to one real measurement each).
+   ``--smoke`` wall-clocks toy grids (``measured_scale="small"``).
 
   PYTHONPATH=src python -m benchmarks.fig_fidelity
   PYTHONPATH=src python -m benchmarks.fig_fidelity --smoke --measured
@@ -53,7 +54,7 @@ def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--measured", action="store_true",
                     help="also run the measured-fidelity search "
-                         "(subprocess wall clocks; slowest section)")
+                         "(in-process wall clocks; slowest section)")
     ap.add_argument("--repeats", type=int, default=3,
                     help="wall-clock repeats per probe/individual")
     add_common_args(ap)
@@ -88,12 +89,13 @@ def main(argv=None) -> None:
 
     # 2) calibrated pipeline: search under the fitted machine (the
     # section-1 calibration is injected — probes are measured ONCE)
+    scale = "small" if args.smoke else "model"
     budget = dict(population=6, generations=4) if args.smoke else {}
     for app in ("himeno",) if args.smoke else ("himeno", "nasft"):
         spec = OffloadSpec(program=app, fidelity="calibrated",
                            repeats=args.repeats, seed=args.seed,
                            workers=args.workers, cache=args.cache,
-                           **budget)
+                           measured_scale=scale, **budget)
         res = Offloader(
             spec, artifact_path=os.path.join(tmp, f"{app}-cal.json"),
             calibration=cal,
@@ -107,18 +109,18 @@ def main(argv=None) -> None:
         print("csv:calibrated," + app + ","
               + ",".join(f"{r['ratio']:.4f}" for r in fid["rows"]))
 
-    # 3) measured pipeline: real subprocess wall clocks
+    # 3) measured pipeline: real in-process wall clocks
     if args.measured or args.smoke:
         spec = OffloadSpec(program="himeno", fidelity="measured",
-                           executor="process", workers=max(2, args.workers),
-                           repeats=args.repeats, population=4,
+                           repeats=args.repeats, measured_scale=scale,
+                           population=4,
                            generations=2, seed=args.seed,
                            cache=os.path.join(tmp, "measured.jsonl"))
         res = Offloader(
             spec, artifact_path=os.path.join(tmp, "himeno-meas.json")
         ).run()
         p = res.stage("search").payload
-        print("\n== measured search: himeno (subprocess wall clocks) ==")
+        print("\n== measured search: himeno (in-process wall clocks) ==")
         print(f"  winner {res.best_time_s:.4g}s from "
               f"{p['evaluations']} real measurements "
               f"({p['cache_hits']} cache hits)")

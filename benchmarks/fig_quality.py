@@ -40,8 +40,8 @@ def _spec(program: str, args, *, diversity: float = 0.0,
                       stability_window=args.window,
                       rank_probe=rank_probe),
     )
-    if args.smoke:
-        kw.update(population=6, generations=4)
+    if args.smoke:  # toy budget, and rank probes clock toy grids
+        kw.update(population=6, generations=4, measured_scale="small")
     return OffloadSpec(**kw)
 
 

@@ -14,11 +14,11 @@ implementation instead. This module is the library side of that step:
   entries. The fingerprint covers every field an evaluator prices from
   (signatures, destination kinds, gains), so block-enabled fitness-cache
   entries are keyed on the exact library that produced them.
-- :func:`oracle_check` runs an entry's implementation (Pallas kernel
-  body via ``interpret=True``) against its ``ref.py`` oracle on a tiny
-  seeded input — the verify stage calls this for every substitution the
-  search placed in a winner, the same way PCAST validates loop
-  placements.
+- :func:`oracle_check` runs an entry's implementation (the Pallas kernel,
+  compiled for the chip on a TPU backend and interpreted elsewhere)
+  against its ``ref.py`` oracle on a tiny seeded input — the verify
+  stage calls this for every substitution the search placed in a
+  winner, the same way PCAST validates loop placements.
 
 Signatures are derived from the same per-loop fields that
 ``LoopProgram.fingerprint()`` digests: :func:`loop_atom` renders the
@@ -172,6 +172,14 @@ def default_library(hw: Optional[str] = None) -> KernelLibrary:
 _ORACLE_TOL = {"rtol": 2e-5, "atol": 2e-5}
 
 
+def kernel_interpret() -> bool:
+    """Whether the library's Pallas kernels run interpreted: only off the
+    TPU. On a TPU backend they are compiled for the chip."""
+    import jax
+
+    return jax.default_backend() != "tpu"
+
+
 def _attention_case(seed: int):
     import numpy as np
 
@@ -183,7 +191,7 @@ def _attention_case(seed: int):
     k = rng.standard_normal((B, S, H, D)).astype("float32")
     v = rng.standard_normal((B, S, H, D)).astype("float32")
     impl = lambda: ops.flash_attention(  # noqa: E731
-        q, k, v, causal=True, impl="pallas", interpret=True
+        q, k, v, causal=True, impl="pallas", interpret=kernel_interpret()
     )
     oracle = lambda: ref.attention_ref(q, k, v, causal=True)  # noqa: E731
     return impl, oracle, f"q{q.shape}"
@@ -202,7 +210,8 @@ def _ssd_case(seed: int):
     Bm = rng.standard_normal((B, S, N)).astype("float32")
     Cm = rng.standard_normal((B, S, N)).astype("float32")
     impl = lambda: ops.ssd_scan(  # noqa: E731
-        x, dt, A, Bm, Cm, chunk=chunk, impl="pallas", interpret=True
+        x, dt, A, Bm, Cm, chunk=chunk, impl="pallas",
+        interpret=kernel_interpret(),
     )
     oracle = lambda: ref.ssd_ref(x, dt, A, Bm, Cm, chunk=chunk)  # noqa: E731
     return impl, oracle, f"x{x.shape}"
@@ -216,14 +225,19 @@ _ORACLE_HARNESSES: Dict[str, Callable] = {
 
 
 def oracle_check(entry: KernelEntry, seed: int = 0) -> Dict[str, object]:
-    """Run ``entry``'s implementation (real kernel body, interpret mode)
-    against its reference oracle on a tiny seeded input. Returns a
-    JSON-able verdict row for the verify stage's ``block_oracles``."""
+    """Run ``entry``'s implementation (the real kernel, compiled on a TPU
+    and interpreted elsewhere) against its reference oracle on a tiny
+    seeded input. Returns a JSON-able verdict row for the verify stage's
+    ``block_oracles``. Both sides multiply at full f32 precision: the
+    tolerance is an f32 one, and a TPU's default f32 matmul is a single
+    bf16 pass."""
+    import jax
     import numpy as np
 
     impl, oracle, shape = _ORACLE_HARNESSES[entry.name](seed)
-    got = np.asarray(impl())
-    want = np.asarray(oracle())
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(impl())
+        want = np.asarray(oracle())
     err = float(np.max(np.abs(got - want))) if got.size else 0.0
     tol = _ORACLE_TOL["atol"] + _ORACLE_TOL["rtol"] * float(
         np.max(np.abs(want))
@@ -235,6 +249,7 @@ def oracle_check(entry: KernelEntry, seed: int = 0) -> Dict[str, object]:
         "shape": shape,
         "max_abs_err": err,
         "tol": tol,
+        "interpret": kernel_interpret(),
         "ok": bool(err <= tol),
     }
 

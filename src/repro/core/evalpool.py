@@ -20,6 +20,15 @@ ways, without changing GA semantics:
   evaluator-provided ``evaluate_batch`` (the ``CompiledEvaluator``'s
   batched AOT-compile path).
 
+An exception from the evaluator scores as the penalty (the paper's
+compile-error analogue) unless the evaluator sets
+``measures_device = True`` (``MeasuredEvaluator`` does): then it
+propagates and fails the run — a crashed clock says nothing about the
+placement, and a penalty would hide the failure behind an all-host win.
+Such an evaluator also runs only in-line — one measurement at a time, in
+this process: the chip belongs to the process that touched JAX first, so
+a child would find it held, and two clocks on it would time each other.
+
 Determinism: the GA's RNG stream never depends on evaluation order or
 worker count, and results are reduced back into population order, so a
 fixed seed produces the same best individual at pool size 1 and N.
@@ -384,6 +393,10 @@ class GenTelemetry:
 GenerationTelemetry = GenTelemetry
 
 
+def _measures_device(evaluate: Callable) -> bool:
+    return bool(getattr(evaluate, "measures_device", False))
+
+
 def _timed_call(
     evaluate: Callable[[Genes], float], genes: Genes
 ) -> Tuple[float, float]:
@@ -421,7 +434,8 @@ def _run_with_executor(
         # spawn, not fork: the parent has usually initialized JAX/XLA
         # (runtime threads + locks), and forking that state can deadlock
         # the child mid-measurement. Spawn requires the evaluator to be
-        # picklable — module-level run_fns like miniapps.HimenoRunFn.
+        # picklable. Never for work that needs the chip: the parent that
+        # touched JAX holds it, so a child would fail or fall back.
         ex = cf.ProcessPoolExecutor(
             max_workers=max(1, workers), mp_context=mp.get_context("spawn")
         )
@@ -493,13 +507,13 @@ class EvalPool:
         executor = serial in-line execution (no executor; byte-identical
         to the pre-pool GA loop, and what ``run_ga`` builds when no pool
         is passed). A process pool runs through the executor even at
-        workers=1: its subprocess isolation is semantic, not just
-        parallelism.
+        workers=1: the caller asked for child processes.
     executor:
-        "thread" (default) or "process". Threads suit the analytic and
-        compiled evaluators (numpy/XLA release the GIL); processes suit
-        CPU-bound Python ``run_fn``s fed to ``MeasuredEvaluator`` —
-        but require picklable evaluators.
+        "thread" (default) or "process". Threads suit the analytic,
+        compiled and measured evaluators (numpy/XLA release the GIL, and
+        a measurement must run in the process that holds the chip);
+        processes suit CPU-bound pure-Python evaluators that never touch
+        JAX — and require picklable evaluators.
     cache:
         A :class:`FitnessCache`. Defaults to a fresh in-memory cache.
         If the evaluator provides ``cache_key(genes) -> str``, the POOL
@@ -522,6 +536,12 @@ class EvalPool:
             raise ValueError(f"executor must be thread|process: {executor!r}")
         self.evaluate = evaluate
         self.workers = max(1, int(workers))
+        if _measures_device(evaluate) and (executor, self.workers) != (
+                "thread", 1):
+            raise ValueError(
+                "a device measurement runs one at a time, in the process "
+                "that holds the chip; use executor='thread', workers=1"
+            )
         self.executor = executor
         # a cache the pool built itself is closed by close(); a CALLER's
         # cache is left open — it may be serving other pools (the
@@ -635,9 +655,8 @@ class EvalPool:
             except Exception:
                 pass  # batch path degraded; fall through to point-wise
         # the inline shortcut (byte-identical to the pre-pool GA loop)
-        # applies to THREAD pools only: a process pool's subprocess
-        # isolation is the point even at workers=1 — measured-fidelity
-        # searches must never wall-clock inside the driver process
+        # applies to THREAD pools only: a process pool was asked for
+        # child processes, even at workers=1
         if self.workers == 1 and self.executor == "thread":
             out: List[Tuple[float, bool, float]] = []
             for g in misses:
@@ -645,6 +664,8 @@ class EvalPool:
                     v, dur = _timed_call(self.evaluate, g)
                     out.append((v, False, dur))
                 except Exception:
+                    if _measures_device(self.evaluate):
+                        raise
                     out.append((float("inf"), True, 0.0))
             return out, 1
         raw = _run_with_executor(
@@ -802,6 +823,8 @@ class SteadySession:
             try:
                 raw = float(self.pool.evaluate(ind))
             except Exception:
+                if _measures_device(self.pool.evaluate):
+                    raise
                 raw = float("inf")
             self._resolve(key, raw)
         else:

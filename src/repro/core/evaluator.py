@@ -10,9 +10,9 @@ Three evaluators, one per fidelity level:
   system. Constants were calibrated once against the paper's measured
   end-points (see ``calibration`` note below) and then frozen.
 
-- ``MeasuredEvaluator`` — actually runs a miniapp implementation on this
-  container and wall-clocks it (the paper's real measurement loop, with
-  timeout -> penalty handled by the GA).
+- ``MeasuredEvaluator`` — actually runs a miniapp implementation in this
+  process and wall-clocks it on the device JAX gives it (the paper's real
+  measurement loop, with timeout -> penalty handled by the GA).
 
 - ``CompiledEvaluator`` — framework level: genes -> ExecutionPlan ->
   AOT ``.lower().compile()`` on the production mesh -> three-term roofline
@@ -22,6 +22,7 @@ Three evaluators, one per fidelity level:
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
@@ -202,23 +203,48 @@ class MiniappEvaluator:
 
 
 # ---------------------------------------------------------------------------
-# measured evaluator (this container's real verification environment)
+# measured evaluator (the real verification environment)
 # ---------------------------------------------------------------------------
+
+# One measurement at a time on the device. A chip belongs to one process,
+# so every measurement runs in the process that holds it; the runnable
+# implementations place their arrays on the default device, and two
+# clocks sharing it (concurrent service jobs) would time each other.
+DEVICE_LANE = threading.Lock()
+
+
+def device_info() -> Dict[str, object]:
+    """What JAX measures on: ``{"platform", "device_kind", "count"}``."""
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "count": len(devs)}
 
 
 class MeasuredEvaluator:
     """Wall-clocks ``run_fn(genes)``; the GA applies the timeout penalty.
 
     Measurements are machine-bound facts: the fingerprint carries the
-    *measurement identity* — run_fn, repeat count, config tag AND the
-    host the clock ran on — so a persistent fitness cache can hold
-    modeled and measured entries side by side without ever serving one
-    host's (or the analytic model's) numbers to another.
+    *measurement identity* — run_fn, repeat count, config tag, the host
+    the clock ran on AND the device (platform and kind) — so a persistent
+    fitness cache can hold modeled and measured entries side by side
+    without ever serving one machine's (or the analytic model's, or the
+    CPU's) numbers to another.
+
+    ``measures_device``: the clock runs work on this process's device, so
+    an :class:`~repro.core.evalpool.EvalPool` runs it in-line, one at a
+    time (a child would find the chip held), and a measurement that
+    raises fails the run — unlike a compile error, a crashed clock says
+    nothing about the placement.
     """
+
+    measures_device = True
 
     def __init__(self, run_fn: Callable[[Sequence[int]], None],
                  repeats: int = 1, tag: str = "default",
-                 host: Optional[str] = None):
+                 host: Optional[str] = None, device: Optional[str] = None):
         self.run_fn = run_fn
         self.repeats = repeats
         # qualnames don't distinguish lambdas/partials/closures that differ
@@ -226,13 +252,18 @@ class MeasuredEvaluator:
         # sharing a persistent fitness cache
         self.tag = tag
         self.host = host if host is not None else _local_host()
+        if device is None:
+            d = device_info()
+            device = f"{d['platform']}:{d['device_kind']}"
+        self.device = device
 
     def __call__(self, genes: Sequence[int]) -> float:
         best = float("inf")
-        for _ in range(self.repeats):
-            t0 = time.perf_counter()
-            self.run_fn(genes)
-            best = min(best, time.perf_counter() - t0)
+        with DEVICE_LANE:  # the clock starts once the device is ours
+            for _ in range(self.repeats):
+                t0 = time.perf_counter()
+                self.run_fn(genes)
+                best = min(best, time.perf_counter() - t0)
         return best
 
     def cache_key(self, genes: Sequence[int]) -> str:
@@ -250,7 +281,7 @@ class MeasuredEvaluator:
             or type(self.run_fn).__name__
         mod = getattr(self.run_fn, "__module__", "")
         return (f"measured:{mod}.{name}:r{self.repeats}:{self.tag}"
-                f"@{self.host}")
+                f"@{self.host}:{self.device}")
 
 
 def _local_host() -> str:

@@ -10,12 +10,13 @@ Two levels per app, mirroring the paper's verification environment:
 2. **Runnable implementation** (``himeno_run`` / ``nasft_run``) — the same
    computation in JAX, where each offloadable loop executes either on the
    "CPU path" (pure NumPy, interpreter-rate) or the "accelerator path"
-   (jitted JAX) according to the genome. This gives the GA a *measured*
-   verification environment on this container and gives PCAST real
-   CPU-vs-accelerator outputs to diff.
+   (jitted JAX on the default device — the TPU where one is attached)
+   according to the genome. This gives the GA a *measured* verification
+   environment and gives PCAST real CPU-vs-accelerator outputs to diff.
 
-Sizes default to scaled-down grids so measured GA runs finish quickly;
-the LoopProgram carries the paper-scale sizes for the analytic evaluator.
+The LoopProgram carries the paper-scale sizes for the analytic evaluator;
+the run fns below take their grid explicitly (``repro.offload.programs``
+decides the measured scale in one table).
 """
 from __future__ import annotations
 
@@ -606,15 +607,13 @@ def nasft_run(
 
 
 # ===========================================================================
-# Picklable genes->run callables (MeasuredEvaluator + process EvalPools)
+# genes->run callables (MeasuredEvaluator)
 # ===========================================================================
 #
 # ``MeasuredEvaluator`` wall-clocks ``run_fn(genes)``. The runnable
 # implementations above expose ONE offload switch (jitted JAX vs numpy),
 # so the run fn collapses the genome to the gene of the designated hot
-# loop. Defined as frozen module-level dataclasses — not closures — so a
-# ``ProcessPoolExecutor`` (``EvalPool(executor="process")``) can pickle
-# the evaluator into its workers.
+# loop. Frozen dataclasses: the config is the value, and the tag names it.
 
 
 def _gene_index(prog: LoopProgram, loop_name: str) -> int:
@@ -641,12 +640,21 @@ def _hot_gene(prog_fn, loop_name: str) -> int:
 class HimenoRunFn:
     """genes -> run Himeno; the ``jacobi_stencil`` gene picks the path."""
 
-    grid: Tuple[int, int, int] = (9, 9, 17)
-    nn: int = 2
+    grid: Tuple[int, int, int]
+    nn: int
+
+    def run(self, offloaded: bool) -> Dict[str, Any]:
+        """One solve on the jitted (offloaded) or numpy (host) path; the
+        outputs PCAST compares."""
+        p, gosa = himeno_run(self.grid, self.nn, jit_stencil=offloaded)
+        return {"p": p, "gosa": np.float32(gosa)}
+
+    def pair(self, offloaded: bool) -> Tuple[Dict[str, Any], Dict[str, Any]]:
+        """(host reference, placement under test) for PCAST."""
+        return self.run(False), self.run(offloaded)
 
     def __call__(self, genes: Sequence[int]) -> None:
-        hot = _hot_gene(himeno_program, "jacobi_stencil")
-        himeno_run(self.grid, self.nn, jit_stencil=bool(genes[hot]))
+        self.run(bool(genes[_hot_gene(himeno_program, "jacobi_stencil")]))
 
     def cache_key(self, genes: Sequence[int]) -> str:
         """Canonical measurement key: the implementation only branches on
@@ -661,17 +669,29 @@ class HimenoRunFn:
         """Cache tag for MeasuredEvaluator (captures the config)."""
         return f"himeno:{'x'.join(map(str, self.grid))}:nn{self.nn}"
 
+    def program(self) -> LoopProgram:
+        """The LoopProgram at this run's config (the scale its clock
+        and its model prediction share)."""
+        return himeno_program(grid=self.grid, nn=self.nn)
+
 
 @dataclasses.dataclass(frozen=True)
 class NasftRunFn:
     """genes -> run NAS.FT; the ``evolve`` gene picks the path."""
 
-    grid: Tuple[int, int, int] = (8, 8, 8)
-    niter: int = 2
+    grid: Tuple[int, int, int]
+    niter: int
+
+    def run(self, offloaded: bool) -> Dict[str, Any]:
+        return {"checksums": nasft_run(self.grid, self.niter,
+                                       jit_fft=offloaded)}
+
+    def pair(self, offloaded: bool) -> Tuple[Dict[str, Any], Dict[str, Any]]:
+        """See :meth:`HimenoRunFn.pair`."""
+        return self.run(False), self.run(offloaded)
 
     def __call__(self, genes: Sequence[int]) -> None:
-        hot = _hot_gene(nasft_program, "evolve")
-        nasft_run(self.grid, self.niter, jit_fft=bool(genes[hot]))
+        self.run(bool(genes[_hot_gene(nasft_program, "evolve")]))
 
     def cache_key(self, genes: Sequence[int]) -> str:
         """See :meth:`HimenoRunFn.cache_key`."""
@@ -681,6 +701,9 @@ class NasftRunFn:
     @property
     def tag(self) -> str:
         return f"nasft:{'x'.join(map(str, self.grid))}:it{self.niter}"
+
+    def program(self) -> LoopProgram:
+        return nasft_program(grid=self.grid, niter=self.niter)
 
 
 MINIAPPS = {

@@ -8,7 +8,6 @@ chunked reference so dry-run FLOPs match the kernel path).
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -25,8 +24,6 @@ def _use_pallas(force: Optional[str]) -> bool:
     if force == "pallas":
         return True
     if force in ("ref", "chunked"):
-        return False
-    if os.environ.get("REPRO_FORCE_REF"):
         return False
     return jax.default_backend() == "tpu"
 

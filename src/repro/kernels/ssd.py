@@ -7,29 +7,34 @@ matrix L, then @ X) and carry the inter-chunk recurrent state (P x N, f32) in
 VMEM scratch across the sequential chunk grid dimension — the TPU grid's
 last-dim sequential guarantee replaces the GPU's inter-block atomics.
 
-grid = (B, H, S/chunk); chunk dim sequential.
-BlockSpec tiles per step: x (1, chunk, 1, P), dt (1, chunk, 1),
-B/C (1, chunk, N) — with chunk=256, P=64..128, N=64..128 everything
-(inputs + L matrix (chunk x chunk f32) + state scratch) is « 1 MB VMEM.
+grid = (B, H, S/chunk); chunk dim sequential. The kernel runs over a
+head-major ``(B, H, S, P)`` layout so every block's last two dimensions
+obey the TPU tiling (a multiple of (8, 128) or the whole dimension):
+x (chunk, P), dt (chunk, 1) over ``(B, H, S, 1)``, B/C (chunk, N); A
+rides whole in SMEM. The in-chunk cumulative sum of the log-decays is a
+triangular-mask matmul (Mosaic has no cumsum). With chunk=256, P=64..128,
+N=64..128 everything (inputs + L matrix (chunk x chunk f32) + state
+scratch) is « 1 MB VMEM.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_EXACT = jax.lax.Precision.HIGHEST  # the decay cumsum must stay f32-exact
+
 
 def _ssd_kernel(
-    x_ref,  # (1, chunk, 1, P)  — dt-weighted input block
-    dt_ref,  # (1, chunk, 1)
-    a_ref,  # (1, 1)            — A value for this head (SMEM)
-    b_ref,  # (1, chunk, N)
-    c_ref,  # (1, chunk, N)
-    y_ref,  # (1, chunk, 1, P)
+    a_ref,  # (H,)              — A per head (SMEM, whole array)
+    x_ref,  # (chunk, P)        — dt-weighted input block
+    dt_ref,  # (chunk, 1)
+    b_ref,  # (chunk, N)
+    c_ref,  # (chunk, N)
+    y_ref,  # (chunk, P)
     state_scr,  # (P, N) f32 VMEM scratch — inter-chunk recurrent state
     *,
     chunk: int,
@@ -40,20 +45,29 @@ def _ssd_kernel(
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)  # (cs, P) — already dt-weighted
-    dt = dt_ref[0, :, 0].astype(jnp.float32)  # (cs,)
-    a = a_ref[0, 0]
-    bm = b_ref[0].astype(jnp.float32)  # (cs, N)
-    cm = c_ref[0].astype(jnp.float32)  # (cs, N)
+    x = x_ref[...].astype(jnp.float32)  # (cs, P) — already dt-weighted
+    dA = dt_ref[...].astype(jnp.float32) * a_ref[pl.program_id(1)]  # (cs, 1)
+    bm = b_ref[...].astype(jnp.float32)  # (cs, N)
+    cm = c_ref[...].astype(jnp.float32)  # (cs, N)
 
-    dA = dt * a  # (cs,) log-decay increments (negative)
-    cum = jnp.cumsum(dA)  # (cs,)
-
-    # intra-chunk: L[i,j] = exp(cum_i - cum_j) for j <= i
     li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    seg = cum[:, None] - cum[None, :]
-    L = jnp.where(li >= lj, jnp.exp(seg), 0.0)  # (cs, cs)
+    causal = li >= lj
+    # in-chunk cumulative log-decay cum[i] = sum_{k<=i} dA[k], broadcast
+    # across N lanes (cum_lanes) and laid out as a row (cum_row)
+    dA_lanes = jnp.broadcast_to(dA, (chunk, bm.shape[1]))  # (cs, N)
+    cum_lanes = jax.lax.dot_general(
+        causal.astype(jnp.float32), dA_lanes, (((1,), (0,)), ((), ())),
+        precision=_EXACT, preferred_element_type=jnp.float32,
+    )  # (cs, N)
+    cum = cum_lanes[:, :1]  # (cs, 1)
+    cum_row = jax.lax.dot_general(
+        dA_lanes, (li <= lj).astype(jnp.float32), (((0,), (0,)), ((), ())),
+        precision=_EXACT, preferred_element_type=jnp.float32,
+    )[:1, :]  # (1, cs)
+
+    # intra-chunk: L[i,j] = exp(cum_i - cum_j) for j <= i
+    L = jnp.where(causal, jnp.exp(cum - cum_row), 0.0)  # (cs, cs)
     scores = jax.lax.dot_general(
         cm, bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (cs, cs) = C B^T
@@ -63,22 +77,21 @@ def _ssd_kernel(
     )  # (cs, P)
 
     # inter-chunk: contribution of carried state
-    state_decay = jnp.exp(cum)  # (cs,)
     y_inter = (
         jax.lax.dot_general(
             cm, state_scr[...], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        * state_decay[:, None]
+        * jnp.exp(cum)
     )  # (cs, P)
 
-    y_ref[0, :, 0, :] = (y_intra + y_inter).astype(y_ref.dtype)
+    y_ref[...] = (y_intra + y_inter).astype(y_ref.dtype)
 
     # state update: state' = e^{sum dA} state + X^T (B * decay_to_end)
-    total = cum[chunk - 1]
-    decay_to_end = jnp.exp(total - cum)  # (cs,)
+    total = cum_lanes[chunk - 1:, :]  # (1, N)
+    decay_to_end = jnp.exp(total - cum_lanes)  # (cs, N)
     upd = jax.lax.dot_general(
-        x, bm * decay_to_end[:, None], (((0,), (0,)), ((), ())),
+        x, bm * decay_to_end, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )  # (P, N)
     state_scr[...] = state_scr[...] * jnp.exp(total) + upd
@@ -100,24 +113,26 @@ def ssd_pallas(
     assert S % chunk == 0, (S, chunk)
     nc = S // chunk
 
-    xw = (x * dt[..., None]).astype(x.dtype)  # dt-weighted input
-    a2d = A.reshape(H, 1).astype(jnp.float32)
+    # head-major layouts: (B, H, S, P) and (B, H, S, 1)
+    xw = (x * dt[..., None]).astype(x.dtype).transpose(0, 2, 1, 3)
+    dth = dt.transpose(0, 2, 1)[..., None]
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
-    return pl.pallas_call(
+    y = pl.pallas_call(
         kernel,
         grid=(B, H, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec(
-                (1, 1), lambda b, h, c: (h, 0), memory_space=pltpu.SMEM
-            ),
-            pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, None, chunk, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((None, None, chunk, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((None, chunk, N), lambda b, h, c: (b, c, 0)),
+            pl.BlockSpec((None, chunk, N), lambda b, h, c: (b, c, 0)),
         ],
-        out_specs=pl.BlockSpec((1, chunk, 1, P), lambda b, h, c: (b, c, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, P), x.dtype),
+        out_specs=pl.BlockSpec(
+            (None, None, chunk, P), lambda b, h, c: (b, h, c, 0)
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(xw, dt, a2d, Bm, Cm)
+    )(A.astype(jnp.float32), xw, dth, Bm, Cm)
+    return y.transpose(0, 2, 1, 3)

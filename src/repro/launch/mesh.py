@@ -3,13 +3,8 @@
 A FUNCTION (not module-level constant) so importing never touches jax device
 state. Single pod: (16, 16) = 256 chips, axes (data, model). Multi-pod:
 (2, 16, 16) = 512 chips, axes (pod, data, model); the pod axis is a pure
-data-parallel/FSDP axis crossing the inter-pod links.
-
-``compat_make_mesh`` absorbs the ``axis_types=`` API drift: newer jax
-accepts (and eventually wants) explicit ``jax.sharding.AxisType.Auto``
-axis types; jax<=0.4.x has neither the kwarg nor the enum, and its meshes
-are Auto-typed implicitly — so omitting the kwarg there is semantically
-identical.
+data-parallel/FSDP axis crossing the inter-pod links. Every mesh is built
+with explicit ``Auto`` axis types.
 """
 from __future__ import annotations
 
@@ -18,26 +13,22 @@ from typing import Sequence, Tuple
 import jax
 
 
-def compat_make_mesh(shape: Tuple[int, ...], axes: Sequence[str]):
-    """jax.make_mesh with Auto axis types across jax versions."""
-    try:
-        return jax.make_mesh(
-            shape, tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(shape),
-        )
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, tuple(axes))
+def _auto_mesh(shape: Tuple[int, ...], axes: Sequence[str]):
+    return jax.make_mesh(
+        shape, tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(shape),
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(model: int = 4, data: int = 2):
     """Small host-device mesh for tests (requires device_count >= data*model)."""
-    return compat_make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_mesh_shape(spec: str):
@@ -49,6 +40,6 @@ def make_mesh_shape(spec: str):
     logical mesh differs from the baseline (16, 16)."""
     dims = tuple(int(x) for x in spec.split("x"))
     if len(dims) == 2:
-        return compat_make_mesh(dims, ("data", "model"))
+        return _auto_mesh(dims, ("data", "model"))
     assert len(dims) == 3, dims
-    return compat_make_mesh(dims, ("pod", "data", "model"))
+    return _auto_mesh(dims, ("pod", "data", "model"))

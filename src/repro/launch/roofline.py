@@ -60,15 +60,9 @@ _ELEMENTWISE = frozenset(
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized to one flat dict.
-
-    jax<=0.4.x returns a list with one per-program dict; newer jax returns
-    the dict directly; some backends return None.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    return dict(ca) if isinstance(ca, dict) else {}
+    """``compiled.cost_analysis()`` as a dict ({} where the backend
+    reports none)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def _dims(dim_str: str) -> Tuple[int, ...]:

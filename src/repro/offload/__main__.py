@@ -5,7 +5,7 @@
       --destinations cpu,gpu,fpga --warm-start --cache /tmp/hetero.jsonl
   python -m repro.offload run --program hetero --mode mixed --blocks
   python -m repro.offload run --program himeno --fidelity measured \\
-      --workers 2 --population 4 --generations 2
+      --population 4 --generations 2
   python -m repro.offload run --program himeno --smoke   # CI gate
   python -m repro.offload calibrate --base quadro-p4000 \\
       --out p4000.calib.json
@@ -55,6 +55,7 @@ from repro.offload.spec import (
     MODES,
     OffloadSpec,
 )
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 # exit codes per verb, rendered into each subparser's --help epilog and
@@ -137,9 +138,9 @@ def _add_spec_args(p: argparse.ArgumentParser) -> None:
     p.add_argument("--fidelity", choices=list(FIDELITIES),
                    default="modeled",
                    help="how candidates are priced: the analytic model "
-                        "(modeled), real subprocess wall clocks "
-                        "(measured), or the model under constants "
-                        "fitted to this machine (calibrated)")
+                        "(modeled), real in-process wall clocks on the "
+                        "device JAX uses (measured), or the model under "
+                        "constants fitted to this machine (calibrated)")
     p.add_argument("--repeats", type=int, default=1,
                    help="measurement repeats per individual/probe "
                         "(measured/calibrated fidelity)")
@@ -156,9 +157,9 @@ def _add_spec_args(p: argparse.ArgumentParser) -> None:
                         "tuned implementations (docs/blocks.md)")
     p.add_argument("--workers", type=int, default=1)
     p.add_argument("--executor", choices=("thread", "process"),
-                   default=None,
-                   help="measurement executor (default: thread; "
-                        "process under --fidelity measured)")
+                   default="thread",
+                   help="measurement executor (measured fidelity "
+                        "requires thread: it measures in this process)")
     p.add_argument("--cache", default=None, metavar="PATH",
                    help="persistent JSONL fitness cache (resume rides "
                         "on it; `serve` overrides it with the queue "
@@ -204,11 +205,6 @@ def _default_artifact(spec: OffloadSpec) -> str:
 
 
 def _spec_from_args(args: argparse.Namespace) -> OffloadSpec:
-    # --executor defaults per fidelity: measured wall-clocks in spawned
-    # subprocesses (spec validation enforces it), everything else threads
-    executor = args.executor or (
-        "process" if args.fidelity == "measured" else "thread"
-    )
     kw = dict(
         program=args.program,
         mode=args.mode,
@@ -224,7 +220,7 @@ def _spec_from_args(args: argparse.Namespace) -> OffloadSpec:
         warm_start=args.warm_start,
         blocks=args.blocks,
         workers=args.workers,
-        executor=executor,
+        executor=args.executor,
         cache=args.cache,
         rel_tol=args.rel_tol,
         abs_tol=args.abs_tol,
@@ -582,6 +578,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     scan.add_argument("--job", required=True, metavar="ID")
 
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.cmd == "sweep":
         return _cmd_sweep(ap, args)

@@ -705,8 +705,9 @@ class Offloader:
 
     def _block_oracles(self, adapter, best) -> Optional[list]:
         """Kernel-oracle checks for every substitution the winner
-        activates: the substituted implementation (the real kernel body,
-        interpret mode) vs its ``kernels/ref.py`` oracle on a tiny
+        activates: the substituted implementation (the real kernel,
+        compiled on a TPU and interpreted elsewhere) vs its
+        ``kernels/ref.py`` oracle on a tiny
         seeded input — the block analogue of the PCAST placement check.
         None when the run has no block genome (blocks-off byte parity)."""
         subs_fn = getattr(adapter, "substitutions", None)
@@ -749,7 +750,8 @@ class Offloader:
         if spec.fidelity == "measured":
             return self.adapter.model_evaluator()
         eff = self._effective_spec()
-        scale_prog = programs.measured_scale_program(spec.program)
+        scale_prog = programs.measured_run_fn(
+            spec.program, spec.measured_scale).program()
         if spec.mode == "mixed":
             from repro.destinations import MixedEvaluator, get_registry
 
@@ -805,7 +807,7 @@ class Offloader:
         adapter = self.adapter
         n = adapter.gene_length
         zeros = (0,) * n
-        run_fn = programs.MEASURED_RUN_FNS[spec.program]()
+        run_fn = programs.measured_run_fn(spec.program, spec.measured_scale)
         model = self._scale_model()
 
         if spec.fidelity == "measured":
@@ -979,7 +981,7 @@ class Offloader:
                                "measured fidelity ranks for free)"}
         adapter = self.adapter
         n = adapter.gene_length
-        run_fn = programs.MEASURED_RUN_FNS[spec.program]()
+        run_fn = programs.measured_run_fn(spec.program, spec.measured_scale)
         model = self._scale_model()
         pop = [tuple(int(g) for g in ind) for ind in final]
         modeled = [float(model(g)) for g in pop]
@@ -1072,6 +1074,12 @@ def render_report(result: OffloadResult,
             + (f" / {a['n_loops']} loops" if "n_loops" in a else "")
             + f"; all-host baseline {a['baseline_s']:.4g}s"
         )
+        d = a.get("device")
+        if d:  # measured fidelity: what the clocks ran on
+            rows.append(
+                f"device: {d['platform']} ({d['device_kind']}) x{d['count']}"
+                f", measured at {a['measured_scale']}"
+            )
     if result.completed("seed"):
         s = result.stage("seed").payload
         if s.get("seeds"):

@@ -23,14 +23,17 @@ from __future__ import annotations
 
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
-import numpy as np
-
 from repro.core import evaluator as ev
 from repro.core import miniapps
 from repro.core import pcast
 from repro.core import transfer as tr
 from repro.core.loopir import LoopClass, LoopProgram
-from repro.offload.spec import MEASURED_PROGRAMS, METHODS, OffloadSpec
+from repro.offload.spec import (
+    MEASURED_PROGRAMS,
+    MEASURED_SCALES,
+    METHODS,
+    OffloadSpec,
+)
 
 # HardwareModel registry (spec.hw); Offloader may inject an unregistered
 # candidate model (calibration sweeps) via its ``hw=`` override.
@@ -80,54 +83,48 @@ def resolve_hw(spec: OffloadSpec,
 
 
 # ---------------------------------------------------------------------------
-# PCAST runnables: genome -> (reference pytree, offloaded pytree)
+# runnable programs: hot loop, run fns, PCAST pairs
 # ---------------------------------------------------------------------------
 
-
-def _himeno_pair(offloaded: bool):
-    p_ref, g_ref = miniapps.himeno_run(grid=(17, 17, 33), nn=4,
-                                       jit_stencil=False)
-    p_off, g_off = miniapps.himeno_run(grid=(17, 17, 33), nn=4,
-                                       jit_stencil=offloaded)
-    return (
-        {"p": p_ref, "gosa": np.float32(g_ref)},
-        {"p": p_off, "gosa": np.float32(g_off)},
-    )
-
-
-def _nasft_pair(offloaded: bool):
-    ref = miniapps.nasft_run(grid=(16, 16, 16), niter=2, jit_fft=False)
-    off = miniapps.nasft_run(grid=(16, 16, 16), niter=2, jit_fft=offloaded)
-    return {"checksums": ref}, {"checksums": off}
-
+# The measured scale, in one table: the run fn (grid + iteration count)
+# every wall clock and measured-fidelity PCAST check of a runnable program
+# uses, per OffloadSpec.measured_scale. "model" runs the grid the searched
+# LoopProgram models — Himeno class M 128x128x256 (~235 MB of float32
+# state), NAS.FT class A 256x256x128 complex64 — with Himeno's Jacobi
+# iterations cut from the modeled 100 to 20 for run time (the host path
+# is numpy; NAS.FT keeps its 6). "small" is a toy grid for CPU tests.
+MEASURED_RUN_FNS: Dict[str, Dict[str, Any]] = {
+    "model": {
+        "himeno": miniapps.HimenoRunFn(grid=(128, 128, 256), nn=20),
+        "nasft": miniapps.NasftRunFn(grid=(256, 256, 128), niter=6),
+    },
+    "small": {
+        "himeno": miniapps.HimenoRunFn(grid=(9, 9, 17), nn=2),
+        "nasft": miniapps.NasftRunFn(grid=(8, 8, 8), niter=2),
+    },
+}
 
 # miniapp name -> (hot loop whose gene selects the accelerator path,
-#                  pair builder). Apps absent here have no runnable
-# implementation; their verify stage records the PCAST check as skipped.
+# PCAST pair builder of the MODELED adapters: a correctness check of the
+# implementation at a small grid, since nothing is timed there). Apps
+# absent here have no runnable implementation; their verify stage
+# records the PCAST check as skipped.
 RUNNABLE: Dict[str, Tuple[str, Callable[[bool], Tuple[Any, Any]]]] = {
-    "himeno": ("jacobi_stencil", _himeno_pair),
-    "nasft": ("evolve", _nasft_pair),
+    "himeno": ("jacobi_stencil",
+               miniapps.HimenoRunFn(grid=(17, 17, 33), nn=4).pair),
+    "nasft": ("evolve", miniapps.NasftRunFn(grid=(16, 16, 16), niter=2).pair),
 }
 
-# measured-fidelity plumbing: the picklable run_fn class per runnable
-# program, and the LoopProgram at the RUN FN's (scaled-down) config — the
-# scale real measurements and their model predictions must both use, so
-# predicted-vs-measured ratios compare like with like (docs/fidelity.md).
-MEASURED_RUN_FNS: Dict[str, Callable[[], Any]] = {
-    "himeno": miniapps.HimenoRunFn,
-    "nasft": miniapps.NasftRunFn,
-}
-
-assert set(MEASURED_RUN_FNS) == set(RUNNABLE) == set(MEASURED_PROGRAMS), \
+assert set(MEASURED_RUN_FNS) == set(MEASURED_SCALES), \
+    "spec.MEASURED_SCALES must list exactly the measured-scale table rows"
+assert all(set(fns) == set(RUNNABLE) == set(MEASURED_PROGRAMS)
+           for fns in MEASURED_RUN_FNS.values()), \
     "spec.MEASURED_PROGRAMS must list exactly the runnable miniapps"
 
 
-def measured_scale_program(name: str) -> LoopProgram:
-    """The program's LoopProgram at its runnable (measured) scale."""
-    fn = MEASURED_RUN_FNS[name]()
-    if name == "himeno":
-        return miniapps.himeno_program(grid=fn.grid, nn=fn.nn)
-    return miniapps.nasft_program(grid=fn.grid, niter=fn.niter)
+def measured_run_fn(program: str, scale: str):
+    """The run fn of a runnable program at a measured scale."""
+    return MEASURED_RUN_FNS[scale][program]
 
 
 def hot_gene_index(name: str) -> int:
@@ -135,6 +132,16 @@ def hot_gene_index(name: str) -> int:
     gene the measured path actually realizes (docs/fidelity.md)."""
     prog = miniapps.MINIAPPS[name]()
     return miniapps._gene_index(prog, RUNNABLE[name][0])
+
+
+def _pcast(pair: Callable[[bool], Tuple[Any, Any]], offloaded: bool,
+           spec: OffloadSpec) -> pcast.PcastReport:
+    """Run a PCAST pair on the device lane (never beside a measurement)
+    and compare it under the spec's tolerances."""
+    with ev.DEVICE_LANE:
+        ref, off = pair(offloaded)
+    return pcast.compare(ref, off, rel_tol=spec.rel_tol,
+                         abs_tol=spec.abs_tol)
 
 
 # ---------------------------------------------------------------------------
@@ -220,9 +227,7 @@ class MiniappBinaryAdapter:
             return None
         loop_name, pair = hot
         offloaded = self.placement(genes)[loop_name] != "cpu"
-        ref, off = pair(offloaded)
-        return pcast.compare(ref, off, rel_tol=self.spec.rel_tol,
-                             abs_tol=self.spec.abs_tol)
+        return _pcast(pair, offloaded, self.spec)
 
 
 class MiniappMeasuredAdapter:
@@ -232,15 +237,16 @@ class MiniappMeasuredAdapter:
 
     The genome still indexes the paper-scale LoopProgram (gene length
     13/65), but fitness comes from ``MeasuredEvaluator`` wall-clocking
-    the picklable run_fn at its scaled-down config inside the spec's
-    ``executor="process"`` EvalPool (spawn context — subprocess
-    isolation is what makes the clock honest). The run_fn's
-    ``cache_key`` collapses genomes to the genes the implementation
-    actually distinguishes (the hot loop), so equivalent placements
-    share one real measurement exactly as the paper's §5.2 cache
-    intends. ``model_evaluator()`` exposes the analytic model AT THE
-    MEASURED SCALE for the verify stage's predicted-vs-measured
-    fidelity section.
+    the run_fn at the spec's measured scale (``MEASURED_RUN_FNS``), one
+    measurement at a time, in this process: a chip belongs to one
+    process, so the process that runs the Offloader is the one that
+    measures. The device is read once, here, and recorded in the analyze
+    payload and the measurement fingerprint. The run_fn's ``cache_key``
+    collapses genomes to the genes the implementation actually
+    distinguishes (the hot loop), so equivalent placements share one real
+    measurement exactly as the paper's §5.2 cache intends.
+    ``model_evaluator()`` exposes the analytic model AT THE MEASURED
+    SCALE for the verify stage's predicted-vs-measured fidelity section.
     """
 
     kind = "miniapp-measured"
@@ -253,8 +259,9 @@ class MiniappMeasuredAdapter:
         self.hw = resolve_hw(spec, hw)  # the MODEL the fidelity section
         # compares against; never used to price candidates
         self.prog: LoopProgram = miniapps.MINIAPPS[spec.program]()
-        self.run_fn = MEASURED_RUN_FNS[spec.program]()
+        self.run_fn = measured_run_fn(spec.program, spec.measured_scale)
         self.method = METHODS[spec.method]
+        self.device = ev.device_info()
 
     @property
     def gene_length(self) -> int:
@@ -270,14 +277,15 @@ class MiniappMeasuredAdapter:
 
     def build_evaluator(self) -> ev.MeasuredEvaluator:
         return ev.MeasuredEvaluator(
-            self.run_fn, repeats=self.spec.repeats, tag=self.run_fn.tag
+            self.run_fn, repeats=self.spec.repeats, tag=self.run_fn.tag,
+            device=f"{self.device['platform']}:{self.device['device_kind']}",
         )
 
     def model_evaluator(self) -> ev.MiniappEvaluator:
         """The analytic model at the measured scale, under the spec's
         method configuration and modeled machine."""
         return ev.MiniappEvaluator(
-            measured_scale_program(self.spec.program),
+            self.run_fn.program(),
             tr.TransferMode(self.method["transfer"]),
             staged=self.method["staged"],
             hw=self.hw,
@@ -285,9 +293,8 @@ class MiniappMeasuredAdapter:
         )
 
     def baseline_time(self) -> float:
-        # a REAL all-host measurement (in-process: the analyze stage is
-        # not pooled, and the number is compared against other wall
-        # clocks, not against model output)
+        # a REAL all-host measurement, compared against other wall
+        # clocks, not against model output
         return float(self.build_evaluator()((0,) * self.gene_length))
 
     def analyze_payload(self) -> Dict[str, Any]:
@@ -300,6 +307,7 @@ class MiniappMeasuredAdapter:
             "fidelity": "measured",
             "measured_scale": self.run_fn.tag,
             "host": e.host,
+            "device": dict(self.device),
             "repeats": self.spec.repeats,
             "loops": [
                 {
@@ -323,11 +331,10 @@ class MiniappMeasuredAdapter:
 
     def pcast_check(self, genes: Sequence[int]
                     ) -> Optional[pcast.PcastReport]:
-        loop_name, pair = RUNNABLE[self.prog.name]
+        # at the measured scale: the check covers what was timed
+        loop_name = RUNNABLE[self.prog.name][0]
         offloaded = self.placement(genes)[loop_name] != "cpu"
-        ref, off = pair(offloaded)
-        return pcast.compare(ref, off, rel_tol=self.spec.rel_tol,
-                             abs_tol=self.spec.abs_tol)
+        return _pcast(self.run_fn.pair, offloaded, self.spec)
 
 
 class MiniappMixedAdapter:
@@ -555,9 +562,7 @@ class MiniappMixedAdapter:
         loop_name, pair = hot
         host = self._evaluator.dests[0].name
         offloaded = self.placement(genes)[loop_name] != host
-        ref, off = pair(offloaded)
-        return pcast.compare(ref, off, rel_tol=self.spec.rel_tol,
-                             abs_tol=self.spec.abs_tol)
+        return _pcast(pair, offloaded, self.spec)
 
 
 class ArchPlanEvaluator:

@@ -46,8 +46,9 @@ MODES = ("binary", "mixed")
 # How candidates are priced, end to end (docs/fidelity.md):
 # - "modeled"    — the analytic HardwareModel/MixedEvaluator (default;
 #                  byte-identical to every pre-fidelity search);
-# - "measured"   — real wall-clocked subprocess runs of the runnable
-#                  miniapps through MeasuredEvaluator + a process EvalPool;
+# - "measured"   — real wall clocks of the runnable miniapps through
+#                  MeasuredEvaluator, one at a time, in the process that
+#                  runs the Offloader (the one that holds the chip);
 # - "calibrated" — a calibrate stage measures a designed probe set, fits
 #                  per-destination constants by least squares, and the
 #                  search runs the analytic model under the fitted machine.
@@ -56,6 +57,12 @@ FIDELITIES = ("modeled", "measured", "calibrated")
 # programs with a runnable implementation the measured/calibrated levels
 # can wall-clock; programs.RUNNABLE must stay in sync (asserted there)
 MEASURED_PROGRAMS = ("himeno", "nasft")
+
+# the scale at which runnable programs are wall-clocked and PCAST-checked
+# (measured fidelity, calibrated fidelity sections, rank probes):
+# "model" = the grid the searched LoopProgram models, "small" = a toy grid
+# for tests on the CPU. programs.MEASURED_RUN_FNS holds the one table.
+MEASURED_SCALES = ("model", "small")
 
 # mixed-mode GA budgets (population, generations): the k=3 space needs
 # ~24x24 to find the mixed optimum on every seed; the smoke budget is
@@ -159,16 +166,20 @@ class OffloadSpec:
     hw: str = "quadro-p4000"
     # -- fidelity: how candidates are priced (FIDELITIES) ------------------
     # "measured" requires a runnable program (MEASURED_PROGRAMS), binary
-    # mode, and executor="process" (real subprocess measurements);
+    # mode, and one in-process measurement lane (executor="thread",
+    # workers=1: the chip belongs to the process running the Offloader);
     # "calibrated" requires ``hw`` to name a known base registry — both
     # validated here at spec time, never mid-search.
     fidelity: str = "modeled"
     # measurement repeats per individual/probe (measured + calibrated).
     # The minimum over repeats is kept, so with the default of 2 the
-    # first repeat absorbs any one-time jit compile (a fresh spawn
-    # worker re-jits) and the clock bills the COMPILED kernel; set 1
-    # only if you explicitly want cold-start costs in the fitness.
+    # first repeat absorbs the one-time jit compile and the clock bills
+    # the COMPILED kernel; set 1 only if you explicitly want cold-start
+    # costs in the fitness.
     repeats: int = 2
+    # MEASURED_SCALES: the grid every wall clock and measured PCAST check
+    # of a runnable program uses (programs.MEASURED_RUN_FNS)
+    measured_scale: str = "model"
     # -- GA budget ---------------------------------------------------------
     population: Optional[int] = None
     generations: Optional[int] = None
@@ -225,6 +236,11 @@ class OffloadSpec:
             )
         if self.repeats < 1:
             raise ValueError(f"repeats must be >= 1: {self.repeats}")
+        if self.measured_scale not in MEASURED_SCALES:
+            raise ValueError(
+                f"measured_scale must be one of {MEASURED_SCALES}: "
+                f"{self.measured_scale!r}"
+            )
         if self.population is not None and self.population < 1:
             raise ValueError(f"population must be >= 1: {self.population}")
         if self.generations is not None and self.generations < 0:
@@ -244,11 +260,19 @@ class OffloadSpec:
                     "runnable implementations switch one CPU/accelerator "
                     "path); use mode='binary'"
                 )
-            if self.executor != "process":
+            if self.executor != "thread":
                 raise ValueError(
-                    "fidelity='measured' wall-clocks real subprocess runs; "
-                    "set executor='process' (the CLI --fidelity measured "
-                    "does this for you)"
+                    "fidelity='measured' measures in the process that runs "
+                    "the Offloader: a chip belongs to one process at a "
+                    "time, so a child process would find it held and JAX "
+                    "would time the CPU instead; use executor='thread'"
+                )
+            if self.workers != 1:
+                raise ValueError(
+                    "fidelity='measured' times one candidate at a time, in "
+                    "the process that holds the chip (concurrent clocks "
+                    f"would time each other); use workers=1, not "
+                    f"{self.workers}"
                 )
         if self.fidelity == "calibrated":
             if self.is_arch:
@@ -346,6 +370,8 @@ class OffloadSpec:
             # serialized only when set: a blocks-off spec round-trips
             # byte-identically to pre-blocks artifacts (same digest)
             del d["blocks"]
+        if self.measured_scale == "model":
+            del d["measured_scale"]  # same rule: default-scale digests
         # same rule for the fast-search knobs: asdict recursed into the
         # nested GAControls, so dropping the off-state keys keeps every
         # knobs-off spec digest identical to pre-fast-search artifacts

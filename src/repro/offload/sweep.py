@@ -256,6 +256,8 @@ def cell_spec(
         # runnable programs: wall-clock the two winner projections so
         # every sweep point records modeled-vs-measured rank fidelity
         kw["ga"] = GAControls(rank_probe=True)
+        if smoke:  # the CI matrix wall-clocks toy grids
+            kw["measured_scale"] = "small"
     return OffloadSpec(**kw)
 
 

@@ -292,25 +292,41 @@ def test_batched_evaluator_path_used():
 
 
 # ---------------------------------------------------------------------------
-# process-pool path: MeasuredEvaluator on real miniapp runs (ROADMAP item)
+# measured path: MeasuredEvaluator on real miniapp runs, in this process
 # ---------------------------------------------------------------------------
 
 
 def test_measured_evaluator_through_process_pool():
-    """The paper's real measurement loop, parallelized: a picklable
-    module-level run_fn (miniapps.HimenoRunFn) wall-clocked by
-    MeasuredEvaluator inside EvalPool(executor="process") workers. The
-    pool spawns (not forks) so the parent's JAX/XLA state can't deadlock
-    the children."""
-    run_fn = miniapps.HimenoRunFn(grid=(9, 9, 17), nn=2)
-    e = ev.MeasuredEvaluator(run_fn, tag=run_fn.tag)
+    """The paper's real measurement loop on a real miniapp run fn: a
+    process pool refuses it (a child would find the chip held by this
+    process), and the in-line pool measures each canonical placement
+    once, in this process."""
+    import multiprocessing
+    import os
+
+    from repro.offload import programs
+
+    run_fn = programs.measured_run_fn("himeno", "small")
+    pids = []
+
+    class InProcess:
+        def __call__(self, genes):
+            pids.append(os.getpid())
+            run_fn(genes)
+
+        def cache_key(self, genes):
+            return run_fn.cache_key(genes)
+
+    e = ev.MeasuredEvaluator(InProcess(), tag=run_fn.tag)
     assert "himeno" in ep.evaluator_fingerprint(e)
+    with pytest.raises(ValueError, match="process"):
+        ep.EvalPool(e, workers=2, executor="process")
 
     prog = miniapps.himeno_program()
     n = prog.gene_length
     off = (0,) * n
     on = tuple(1 for _ in range(n))
-    with ep.EvalPool(e, workers=2, executor="process") as pool:
+    with ep.EvalPool(e) as pool:
         times, tel = pool.evaluate_generation(
             [off, on, off], timeout_s=300.0, penalty_time_s=1000.0
         )
@@ -318,15 +334,20 @@ def test_measured_evaluator_through_process_pool():
     assert tel.timeouts == 0
     assert all(0.0 < t < 300.0 for t in times)
     assert times[0] == times[2]
+    assert pids == [os.getpid()] * 2
+    assert multiprocessing.active_children() == []
 
 
 def test_run_fns_are_picklable():
     import pickle
 
-    for fn in (miniapps.HimenoRunFn(), miniapps.NasftRunFn()):
-        clone = pickle.loads(pickle.dumps(ev.MeasuredEvaluator(fn,
-                                                               tag=fn.tag)))
-        assert clone.tag == fn.tag
+    from repro.offload import programs
+
+    for scale in programs.MEASURED_RUN_FNS.values():
+        for fn in scale.values():
+            clone = pickle.loads(pickle.dumps(
+                ev.MeasuredEvaluator(fn, tag=fn.tag)))
+            assert clone.tag == fn.tag and clone.run_fn == fn
 
 
 # ---------------------------------------------------------------------------

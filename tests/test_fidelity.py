@@ -22,24 +22,23 @@ from repro.offload.__main__ import main as cli_main
 
 @pytest.mark.parametrize("kw,msg", [
     (dict(program="himeno", fidelity="bogus"), "fidelity"),
-    (dict(program="himeno", fidelity="measured", executor="process",
-          repeats=0), "repeats"),
+    (dict(program="himeno", fidelity="measured", repeats=0), "repeats"),
     # measured: non-runnable programs have nothing to wall-clock
-    (dict(program="hetero", fidelity="measured", executor="process"),
-     "runnable"),
-    (dict(program="arch:stablelm-3b", fidelity="measured",
-          executor="process"), "runnable"),
-    # measured: subprocess isolation is mandatory
-    (dict(program="himeno", fidelity="measured"), "process"),
-    (dict(program="himeno", fidelity="measured", executor="thread"),
+    (dict(program="hetero", fidelity="measured"), "runnable"),
+    (dict(program="arch:stablelm-3b", fidelity="measured"), "runnable"),
+    # measured: one clock at a time, in the process that holds the chip
+    (dict(program="himeno", fidelity="measured", executor="process"),
      "process"),
+    (dict(program="himeno", fidelity="measured", workers=2), "process"),
     # measured is a binary-mode feature
-    (dict(program="himeno", fidelity="measured", executor="process",
-          mode="mixed"), "binary"),
+    (dict(program="himeno", fidelity="measured", mode="mixed"), "binary"),
     # calibrated: the base registry must exist
     (dict(program="himeno", fidelity="calibrated", hw="no-such-machine"),
      "base registry"),
     (dict(program="arch:stablelm-3b", fidelity="calibrated"), "machine"),
+    # the measured scale is one of the table's rows
+    (dict(program="himeno", fidelity="measured", measured_scale="huge"),
+     "measured_scale"),
 ])
 def test_fidelity_spec_validation(kw, msg):
     with pytest.raises(ValueError, match=msg):
@@ -47,8 +46,8 @@ def test_fidelity_spec_validation(kw, msg):
 
 
 def test_fidelity_spec_roundtrip():
-    spec = OffloadSpec(program="himeno", fidelity="measured",
-                       executor="process", repeats=3, workers=2)
+    spec = OffloadSpec(program="himeno", fidelity="measured", repeats=3,
+                       measured_scale="small")
     assert OffloadSpec.from_json(spec.to_json()) == spec
 
 
@@ -80,7 +79,7 @@ def test_modeled_fingerprints_unchanged_by_fidelity_subsystem():
 
 
 def test_measured_fingerprint_carries_host_and_repeats():
-    fn = miniapps.HimenoRunFn()
+    fn = op.measured_run_fn("himeno", "small")
     a = ev.MeasuredEvaluator(fn, repeats=1, tag=fn.tag, host="hostA")
     b = ev.MeasuredEvaluator(fn, repeats=2, tag=fn.tag, host="hostA")
     c = ev.MeasuredEvaluator(fn, repeats=1, tag=fn.tag, host="hostB")
@@ -91,8 +90,52 @@ def test_measured_fingerprint_carries_host_and_repeats():
     assert d.host and f"@{d.host}" in d.fingerprint()
 
 
+def test_measured_fingerprint_names_the_device():
+    """CPU clocks and chip clocks never share a fitness-cache entry: the
+    fingerprint carries the platform and device kind JAX reports."""
+    fn = op.measured_run_fn("himeno", "small")
+    cpu = ev.MeasuredEvaluator(fn, tag=fn.tag, host="h", device="cpu:cpu")
+    tpu = ev.MeasuredEvaluator(fn, tag=fn.tag, host="h",
+                               device="tpu:TPU v5 lite")
+    assert cpu.fingerprint() != tpu.fingerprint()
+    assert cpu.fingerprint().endswith("@h:cpu:cpu")
+    # the default is the device this process measures on
+    info = ev.device_info()
+    here = ev.MeasuredEvaluator(fn, tag=fn.tag, host="h")
+    assert here.device == f"{info['platform']}:{info['device_kind']}"
+    assert info["count"] >= 1
+
+
+def test_measured_clocks_take_the_device_one_at_a_time():
+    """Concurrent measurements (two service jobs, one chip) run one after
+    the other: no two run fns are ever inside the clock together."""
+    import threading
+    import time
+
+    active, overlaps = [0], []
+    guard = threading.Lock()
+
+    def run(genes):
+        with guard:
+            active[0] += 1
+            overlaps.append(active[0])
+        time.sleep(0.01)
+        with guard:
+            active[0] -= 1
+
+    e = ev.MeasuredEvaluator(run, repeats=2, tag="lane", host="h",
+                             device="cpu:cpu")
+    threads = [threading.Thread(target=e, args=((i,),)) for i in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30)
+    assert not any(t.is_alive() for t in threads)
+    assert len(overlaps) == 8 and max(overlaps) == 1
+
+
 def test_run_fn_cache_key_collapses_to_hot_gene():
-    fn = miniapps.HimenoRunFn()
+    fn = op.measured_run_fn("himeno", "small")
     e = ev.MeasuredEvaluator(fn, tag=fn.tag)
     n = miniapps.himeno_program().gene_length
     hot = op.hot_gene_index("himeno")
@@ -127,8 +170,9 @@ def test_pool_dedups_on_measured_canonical_key():
 
 
 def test_process_pool_uses_executor_even_at_one_worker(monkeypatch):
-    """executor='process' must never fall back to inline in-driver
-    measurement: the subprocess isolation is the semantics."""
+    """executor='process' at workers=1 still goes through the executor:
+    the caller asked for child processes. Device measurements never do
+    (see test_process_pool_refuses_device_measurements)."""
     seen = {}
 
     def fake(kind, workers, evaluate, genes_list, timeout_s):
@@ -152,8 +196,9 @@ def test_process_pool_uses_executor_even_at_one_worker(monkeypatch):
 
 
 def _measured_spec(**kw):
-    kw.setdefault("executor", "process")
-    return OffloadSpec(program="himeno", fidelity="measured", **kw)
+    # toy grids: these tests clock the CPU, not the chip
+    return OffloadSpec(program="himeno", fidelity="measured",
+                       measured_scale="small", **kw)
 
 
 def test_measured_adapter_resolution_and_shape():
@@ -169,6 +214,10 @@ def test_measured_adapter_resolution_and_shape():
     assert model.prog.description != ad.prog.description
     pay = ad.analyze_payload()
     assert pay["fidelity"] == "measured" and pay["host"] == e.host
+    # the device is read once, when the adapter is built, and recorded
+    assert pay["device"] == ad.device == ev.device_info()
+    assert e.device == f"{ad.device['platform']}:{ad.device['device_kind']}"
+    assert pay["measured_scale"] == "himeno:9x9x17:nn2"
     genes = [0] * 13
     genes[op.hot_gene_index("himeno")] = 1
     assert ad.placement(genes)["jacobi_stencil"] == "gpu"
@@ -319,7 +368,7 @@ def test_calibrated_pipeline_end_to_end(tmp_path, monkeypatch):
     monkeypatch.setattr(calibrate, "DEFAULT_PROBES", small)
     path = str(tmp_path / "cal.offload.json")
     spec = OffloadSpec(program="himeno", fidelity="calibrated",
-                       population=4, generations=3)
+                       measured_scale="small", population=4, generations=3)
     res = Offloader(spec, artifact_path=path).run()
     c = res.stage("calibrate").payload
     assert c["applicable"] and c["entry"] == "quadro-p4000-calibrated"
@@ -400,19 +449,29 @@ def test_measured_verify_refuses_foreign_host_artifact(tmp_path):
 
 @pytest.mark.slow
 def test_measured_fidelity_smoke_through_subprocesses(tmp_path):
-    """Nightly smoke (ISSUE 5 satellite): the whole measured-fidelity
-    pipeline — himeno, tiny budget, spawn-context process pool — prices
-    the winner with real subprocess measurements."""
-    spec = _measured_spec(workers=2, repeats=2, population=4,
-                          generations=2,
+    """Nightly smoke: the whole measured-fidelity pipeline — himeno, tiny
+    budget — prices the winner with real wall clocks taken in THIS
+    process (the one that holds the device), never in a child."""
+    import multiprocessing
+
+    spec = _measured_spec(repeats=2, population=4, generations=2,
                           cache=str(tmp_path / "fitness.jsonl"))
     res = Offloader(spec,
                     artifact_path=str(tmp_path / "m.offload.json")).run()
+    assert multiprocessing.active_children() == []
     p = res.stage("search").payload
     assert p["evaluator"].startswith("measured:")
-    assert p["evaluations"] >= 1  # >=1 real subprocess measurement
+    info = ev.device_info()
+    assert p["evaluator"].endswith(
+        f":{info['platform']}:{info['device_kind']}")
+    assert p["evaluations"] >= 1  # >=1 real in-process measurement
+    assert p["timeouts"] == 0
     assert p["best_time_s"] > 0
-    assert res.stage("analyze").payload["baseline_s"] > 0
+    a = res.stage("analyze").payload
+    assert a["baseline_s"] > 0 and a["device"] == info
+    assert res.stage("verify").payload["pcast"]["ok"]
+    assert f"device: {info['platform']}" in \
+        res.stage("report").payload["text"]
     fid = res.stage("verify").payload["fidelity"]
     assert fid["level"] == "measured" and len(fid["rows"]) == 2
     assert "fidelity[measured" in res.stage("report").payload["text"]
@@ -427,6 +486,45 @@ def test_measured_fidelity_smoke_through_subprocesses(tmp_path):
     assert all(r["genes"].startswith("hot=") for r in measured)
     assert all("measured" not in r["fp"]
                for r in recs if r not in measured)
+
+
+def test_measured_crash_fails_the_run(tmp_path, monkeypatch):
+    """A measurement that raises fails the search loudly instead of being
+    scored as the penalty (which would hand the win to the all-host
+    placement and exit 0)."""
+    real = miniapps.himeno_run
+
+    def crashing(grid, nn, jit_stencil=True):
+        if jit_stencil:
+            raise RuntimeError("device lost mid-measurement")
+        return real(grid, nn, jit_stencil=False)
+
+    monkeypatch.setattr(miniapps, "himeno_run", crashing)
+    spec = _measured_spec(population=4, generations=2)
+    off = Offloader(spec, artifact_path=str(tmp_path / "c.json"))
+    with pytest.raises(RuntimeError, match="device lost"):
+        off.run()
+    assert off.result.stages["search"].status == "failed"
+    assert not off.result.completed("search")
+
+
+def test_process_pool_refuses_device_measurements():
+    """A MeasuredEvaluator never runs in a child process, nor beside
+    another clock: the pool refuses both, and measures in-line."""
+    import os
+
+    pids = []
+
+    def run(genes):
+        pids.append(os.getpid())
+
+    e = ev.MeasuredEvaluator(run, tag="pid", host="h", device="cpu:cpu")
+    for kw in (dict(executor="process"), dict(workers=2)):
+        with pytest.raises(ValueError, match="holds the chip"):
+            ep.EvalPool(e, **kw)
+    with ep.EvalPool(e) as pool:
+        pool.evaluate_generation([(0,), (1,)], 180.0, 1000.0)
+    assert pids == [os.getpid()] * 2
 
 
 # ---------------------------------------------------------------------------

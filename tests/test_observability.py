@@ -292,7 +292,8 @@ def test_stability_disabled_and_injected_evaluator_skips(tmp_path):
 
 
 def test_rank_probe_measures_two_projections(tmp_path):
-    spec = dataclasses.replace(SPEC, ga=GAControls(rank_probe=True))
+    spec = dataclasses.replace(SPEC, ga=GAControls(rank_probe=True),
+                               measured_scale="small")
     res, path = _run(tmp_path, "rp", spec)
     rk = res.stage("report").payload["quality"]["rank"]
     assert "skipped" not in rk
