@@ -520,24 +520,39 @@ def himeno_run(
     dtype=np.float32,
 ):
     """Run the Jacobi solver; returns (p, gosa). ``jit_stencil`` switches the
-    stencil between the jitted JAX path (offloaded) and numpy (host)."""
-    import jax.numpy as jnp
+    stencil between the jitted JAX path (offloaded) and numpy (host).
 
-    s = himeno_init(grid)
+    Each host step runs in a span named ``<program>.<step>``, on the
+    profiler's clock where the chip's operations lie: ``himeno.init``,
+    then on the jitted path ``himeno.copy_in``, a ``himeno.sweep``
+    (dispatch) and a ``himeno.gosa_sync`` per sweep, and
+    ``himeno.copy_out``. Spans stay outside jitted code and never wrap a
+    whole run, so that each names the step the host is in; with no
+    profiler running one costs about a microsecond."""
+    import jax.numpy as jnp
+    from jax.profiler import TraceAnnotation as span
+
+    with span("himeno.init"):
+        s = himeno_init(grid)
 
     if jit_stencil:
         sweep = _himeno_sweep_jit()
-        pj = jnp.asarray(s.p, dtype)
-        aj = jnp.asarray(s.a, dtype)
-        bj = jnp.asarray(s.b, dtype)
-        cj = jnp.asarray(s.c, dtype)
-        bndj = jnp.asarray(s.bnd, dtype)
-        w1j = jnp.asarray(s.wrk1, dtype)
+        with span("himeno.copy_in"):
+            pj = jnp.asarray(s.p, dtype)
+            aj = jnp.asarray(s.a, dtype)
+            bj = jnp.asarray(s.b, dtype)
+            cj = jnp.asarray(s.c, dtype)
+            bndj = jnp.asarray(s.bnd, dtype)
+            w1j = jnp.asarray(s.wrk1, dtype)
         gosa = 0.0
         for _ in range(nn):
-            pj, g = sweep(pj, aj, bj, cj, bndj, w1j)
-            gosa = float(g)
-        return np.asarray(pj, np.float32), gosa
+            with span("himeno.sweep"):
+                pj, g = sweep(pj, aj, bj, cj, bndj, w1j)
+            with span("himeno.gosa_sync"):
+                gosa = float(g)
+        with span("himeno.copy_out"):
+            p = np.asarray(pj, np.float32)
+        return p, gosa
 
     gosa = 0.0
     for _ in range(nn):
@@ -569,17 +584,24 @@ def nasft_run(
     """NAS.FT-style PDE: u1 = IFFT( exp(-4 pi^2 t |k|^2) * FFT(u0) ).
 
     Returns the per-iteration checksums (complex64 ndarray, shape (niter,)).
-    ``jit_fft`` switches the FFT+evolve between jitted JAX and numpy."""
+    ``jit_fft`` switches the FFT+evolve between jitted JAX and numpy.
+
+    Host steps run in spans as in :func:`himeno_run`: ``nasft.init``,
+    then on the jitted path ``nasft.copy_in``, ``nasft.fft`` (dispatch),
+    and per iteration ``nasft.step`` (dispatch), ``nasft.copy_out`` and
+    ``nasft.checksum``."""
     import jax.numpy as jnp
+    from jax.profiler import TraceAnnotation as span
 
     nx, ny, nz = grid
-    rng = np.random.default_rng(314159)
-    u0 = (rng.standard_normal((nz, ny, nx)) +
-          1j * rng.standard_normal((nz, ny, nx))).astype(np.complex64)
-    kz = np.fft.fftfreq(nz)[:, None, None]
-    ky = np.fft.fftfreq(ny)[None, :, None]
-    kx = np.fft.fftfreq(nx)[None, None, :]
-    k2 = (kx**2 + ky**2 + kz**2).astype(np.float32)
+    with span("nasft.init"):
+        rng = np.random.default_rng(314159)
+        u0 = (rng.standard_normal((nz, ny, nx)) +
+              1j * rng.standard_normal((nz, ny, nx))).astype(np.complex64)
+        kz = np.fft.fftfreq(nz)[:, None, None]
+        ky = np.fft.fftfreq(ny)[None, :, None]
+        kx = np.fft.fftfreq(nx)[None, None, :]
+        k2 = (kx**2 + ky**2 + kz**2).astype(np.float32)
     alpha = 1e-2
 
     def checksum(u1):
@@ -589,12 +611,20 @@ def nasft_run(
 
     if jit_fft:
         step = _nasft_step_jit()
-        ut = jnp.fft.fftn(jnp.asarray(u0))
-        k2j = jnp.asarray(k2)
+        with span("nasft.copy_in"):
+            u0j = jnp.asarray(u0)
+            k2j = jnp.asarray(k2)
+        with span("nasft.fft"):
+            ut = jnp.fft.fftn(u0j)
+        del u0j  # free u0 on the chip once the FFT has read it
         sums = []
         for it in range(1, niter + 1):
-            u1 = step(ut, k2j, jnp.float32(it))
-            sums.append(checksum(np.asarray(u1)))
+            with span("nasft.step"):
+                u1 = step(ut, k2j, jnp.float32(it))
+            with span("nasft.copy_out"):
+                u1 = np.asarray(u1)
+            with span("nasft.checksum"):
+                sums.append(checksum(u1))
         return np.asarray(sums, np.complex64)
 
     ut = np.fft.fftn(u0)
