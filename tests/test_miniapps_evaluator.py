@@ -152,6 +152,23 @@ def test_nasft_pcast_jit_vs_numpy():
     assert rep.ok, rep.describe()
 
 
+@pytest.mark.parametrize("shape", [(8, 8, 8), (16, 16, 16), (2, 64, 256)])
+def test_nasft_device_checksum_matches_the_host_checksum(shape):
+    """The jitted path's checksum, reduced on the device, sums the same
+    1024 samples as the host's; 8^3 and 16^3 wrap the stride-17 indices,
+    (2, 64, 256) does not."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(7)
+    u1 = (rng.standard_normal(shape)
+          + 1j * rng.standard_normal(shape)).astype(np.complex64)
+    idx = (np.arange(1024) * 17) % u1.size
+    host = u1.ravel()[idx].sum() / u1.size
+    dev = miniapps._nasft_checksum_jit()(jnp.asarray(u1))
+    assert dev.dtype == jnp.complex64 and dev.shape == ()
+    np.testing.assert_allclose(complex(dev), complex(host), rtol=1e-6)
+
+
 # ---------------------------------------------------------------------------
 # PCAST itself
 # ---------------------------------------------------------------------------

@@ -64,7 +64,7 @@ def test_nasft_spans_each_host_step(tmp_path):
     sums, request, spans = traced(tmp_path, miniapps.nasft_run, **NASFT)
     assert counts(spans) == {"nasft.init": 1, "nasft.copy_in": 1,
                              "nasft.fft": 1, "nasft.step": 2,
-                             "nasft.copy_out": 2, "nasft.checksum": 2}
+                             "nasft.checksum": 2, "nasft.copy_out": 1}
     assert_inside(spans, request)
     np.testing.assert_array_equal(sums, plain)
 

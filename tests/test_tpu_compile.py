@@ -89,6 +89,16 @@ def test_nasft_step_compiles_for_v5e(shape):
     assert "fft" in compiled.as_text().lower()
 
 
+def test_nasft_checksum_compiles_for_v5e(shape):
+    """The device checksum gathers its 1024 samples from the class A
+    field in place: no copy of the field."""
+    nx, ny, nz = programs.measured_run_fn("nasft", "model").grid
+    compiled = _compile(miniapps._nasft_checksum_jit(),
+                        shape((nz, ny, nx), jnp.complex64))
+    assert "gather" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
 def test_flash_attention_compiles_for_v5e(shape):
     """B=1, S=4096, 32 heads of 128 in bf16: a long-context layer."""
     q = shape((1, 4096, 32, 128), jnp.bfloat16)
