@@ -10,8 +10,8 @@ each printing its own lines:
 
 1. device   — JAX version, ``jax.devices()``, compile-cache directory;
               anything but a TPU fails here (no CPU fallback).
-2. himeno   — ``Offloader`` at ``fidelity="measured"`` on Himeno class M
-              (128x128x256), a population-4 / 2-generation search: the
+2. himeno   — ``Offloader`` at ``fidelity="measured"`` on Himeno size M
+              (129x129x257), a population-4 / 2-generation search: the
               all-host baseline, the winner, the hot loop's placement, the
               penalized measurements and the PCAST verdict.
 3. nasft    — the same for NAS FT class A (256x256x128, complex64).

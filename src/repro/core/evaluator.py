@@ -212,6 +212,18 @@ class MiniappEvaluator:
 # clocks sharing it (concurrent service jobs) would time each other.
 DEVICE_LANE = threading.Lock()
 
+# the device-trace span around each run of a program the offload search
+# wall-clocks or checks (chipbench reads it: docs/observability.md)
+MEASURE_SPAN = "offload.measure"
+
+
+def measure_span():
+    """A ``TraceAnnotation`` named :data:`MEASURE_SPAN`; with no profiler
+    running it costs about a microsecond."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(MEASURE_SPAN)
+
 
 def device_info() -> Dict[str, object]:
     """What JAX measures on: ``{"platform", "device_kind", "count"}``."""
@@ -261,9 +273,12 @@ class MeasuredEvaluator:
         best = float("inf")
         with DEVICE_LANE:  # the clock starts once the device is ours
             for _ in range(self.repeats):
-                t0 = time.perf_counter()
-                self.run_fn(genes)
-                best = min(best, time.perf_counter() - t0)
+                # the span opens and closes outside the clock's interval
+                with measure_span():
+                    t0 = time.perf_counter()
+                    self.run_fn(genes)
+                    t = time.perf_counter() - t0
+                best = min(best, t)
         return best
 
     def cache_key(self, genes: Sequence[int]) -> str:

@@ -42,6 +42,8 @@ import dataclasses
 import hashlib
 from typing import Any, Callable, Dict, Optional, Sequence
 
+from jax.profiler import TraceAnnotation
+
 from repro.core import ga
 from repro.core.evalpool import (
     EvalPool,
@@ -187,6 +189,12 @@ class Offloader:
         ``close()`` on what it gets back, so factories must hand out
         refcounted views. ``None`` (the default) keeps single-run
         behavior byte-identical to the pre-serving pipeline.
+    run_fn:
+        fidelity="measured" only: a run fn of the caller's (the shape of
+        ``programs.MEASURED_RUN_FNS``'s rows) in place of the spec's
+        measured-scale row. The search, the verify re-measure and the
+        PCAST pair all run it, and ``result.answer`` is its output.
+        Process-local, like ``evaluator``.
     """
 
     def __init__(
@@ -204,6 +212,7 @@ class Offloader:
         cache_factory: Optional[
             Callable[[Callable], Optional[FitnessCache]]
         ] = None,
+        run_fn=None,
     ):
         if artifact is not None and artifact.spec != spec:
             raise ValueError("artifact was produced by a different spec; "
@@ -219,6 +228,7 @@ class Offloader:
         self._trace_path = trace_path
         self._trace_clock = trace_clock
         self._cache_factory = cache_factory
+        self._run_fn = run_fn
         self._tracer: Optional[trace_mod.TraceWriter] = None
         self._trace_header_written = False
         self._adapter = None  # built lazily (adapters may import jax-side)
@@ -262,7 +272,7 @@ class Offloader:
     def adapter(self):
         if self._adapter is None:
             self._adapter = programs.resolve_adapter(
-                self._effective_spec(), self._hw
+                self._effective_spec(), self._hw, self._run_fn
             )
         return self._adapter
 
@@ -300,6 +310,14 @@ class Offloader:
         calibrate.install(cal, replace=True)
         self._cal = cal
         return cal
+
+    def _measured_run_fn(self):
+        """The run fn this run's clocks and measured PCAST check use: the
+        caller's, else the spec's measured-scale row."""
+        if self._run_fn is not None:
+            return self._run_fn
+        return programs.measured_run_fn(self.spec.program,
+                                        self.spec.measured_scale)
 
     def _search_evaluator(self):
         return self._evaluator if self._evaluator is not None \
@@ -360,7 +378,10 @@ class Offloader:
         t0 = tr.clock() if tr is not None else 0.0
         fn = getattr(self, f"_stage_{name}")
         try:
-            payload, wall = timed(fn)
+            # the device trace's span of the stage, opened where the
+            # JSONL span starts (chipbench's offload-pipeline metrics)
+            with TraceAnnotation(f"offload.{name}"):
+                payload, wall = timed(fn)
         except StageFailure as e:
             if tr is not None:
                 tr.span(name, t0, tr.clock(), "failed", error=str(e))
@@ -662,7 +683,13 @@ class Offloader:
                 "mismatch_rel": mismatch,
                 "consistent": bool(consistent),
             }
-        report = adapter.pcast_check(best)
+        answer_fn = getattr(adapter, "pcast_answer", None)
+        if answer_fn is not None:
+            # measured fidelity: keep what the winner's placed run
+            # produced, in memory only (never in the payload or trace)
+            report, self.result.answer = answer_fn(best)
+        else:
+            report = adapter.pcast_check(best)
         if report is None:
             payload["pcast"] = {
                 "skipped": "no runnable reference implementation",
@@ -807,7 +834,7 @@ class Offloader:
         adapter = self.adapter
         n = adapter.gene_length
         zeros = (0,) * n
-        run_fn = programs.measured_run_fn(spec.program, spec.measured_scale)
+        run_fn = self._measured_run_fn()
         model = self._scale_model()
 
         if spec.fidelity == "measured":
@@ -981,7 +1008,7 @@ class Offloader:
                                "measured fidelity ranks for free)"}
         adapter = self.adapter
         n = adapter.gene_length
-        run_fn = programs.measured_run_fn(spec.program, spec.measured_scale)
+        run_fn = self._measured_run_fn()
         model = self._scale_model()
         pop = [tuple(int(g) for g in ind) for ind in final]
         modeled = [float(model(g)) for g in pop]

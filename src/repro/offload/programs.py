@@ -88,14 +88,15 @@ def resolve_hw(spec: OffloadSpec,
 
 # The measured scale, in one table: the run fn (grid + iteration count)
 # every wall clock and measured-fidelity PCAST check of a runnable program
-# uses, per OffloadSpec.measured_scale. "model" runs the grid the searched
-# LoopProgram models — Himeno class M 128x128x256 (~235 MB of float32
-# state), NAS.FT class A 256x256x128 complex64 — with Himeno's Jacobi
-# iterations cut from the modeled 100 to 20 for run time (the host path
-# is numpy; NAS.FT keeps its 6). "small" is a toy grid for CPU tests.
+# uses, per OffloadSpec.measured_scale. "model" runs the published sizes —
+# Himeno size M, 129x129x257 arrays (himenobmtxpa.c: mimax=129,
+# mjmax=129, mkmax=257; 239 MB of float32 state on the device), NAS.FT
+# class A 256x256x128 complex64 — with Himeno's Jacobi iterations cut from
+# the modeled 100 to 20 for run time (the host path is numpy; NAS.FT keeps
+# its 6). "small" is a toy grid for CPU tests.
 MEASURED_RUN_FNS: Dict[str, Dict[str, Any]] = {
     "model": {
-        "himeno": miniapps.HimenoRunFn(grid=(128, 128, 256), nn=20),
+        "himeno": miniapps.HimenoRunFn(grid=(129, 129, 257), nn=20),
         "nasft": miniapps.NasftRunFn(grid=(256, 256, 128), niter=6),
     },
     "small": {
@@ -247,19 +248,22 @@ class MiniappMeasuredAdapter:
     measurement exactly as the paper's §5.2 cache intends.
     ``model_evaluator()`` exposes the analytic model AT THE MEASURED
     SCALE for the verify stage's predicted-vs-measured fidelity section.
+    ``run_fn``, where given, takes the place of the table's row: the
+    search, the verify re-measure and the PCAST pair all run it.
     """
 
     kind = "miniapp-measured"
     deterministic = False  # wall clocks jitter; re-measure can't be exact
 
     def __init__(self, spec: OffloadSpec,
-                 hw: Optional[ev.HardwareModel] = None):
+                 hw: Optional[ev.HardwareModel] = None, run_fn=None):
         assert spec.fidelity == "measured", spec.fidelity
         self.spec = spec
         self.hw = resolve_hw(spec, hw)  # the MODEL the fidelity section
         # compares against; never used to price candidates
         self.prog: LoopProgram = miniapps.MINIAPPS[spec.program]()
-        self.run_fn = measured_run_fn(spec.program, spec.measured_scale)
+        self.run_fn = run_fn if run_fn is not None else measured_run_fn(
+            spec.program, spec.measured_scale)
         self.method = METHODS[spec.method]
         self.device = ev.device_info()
 
@@ -331,10 +335,24 @@ class MiniappMeasuredAdapter:
 
     def pcast_check(self, genes: Sequence[int]
                     ) -> Optional[pcast.PcastReport]:
-        # at the measured scale: the check covers what was timed
+        return self.pcast_answer(genes)[0]
+
+    def pcast_answer(self, genes: Sequence[int]
+                     ) -> Tuple[pcast.PcastReport, Dict[str, Any]]:
+        """The PCAST check of a placement at the measured scale (so it
+        covers what was timed), and that placement's answer: the output
+        compared against the host path's. Each of the two runs is an
+        ``offload.measure`` span."""
         loop_name = RUNNABLE[self.prog.name][0]
         offloaded = self.placement(genes)[loop_name] != "cpu"
-        return _pcast(self.run_fn.pair, offloaded, self.spec)
+        with ev.DEVICE_LANE:
+            with ev.measure_span():
+                ref = self.run_fn.run(False)
+            with ev.measure_span():
+                off = self.run_fn.run(offloaded)
+        report = pcast.compare(ref, off, rel_tol=self.spec.rel_tol,
+                               abs_tol=self.spec.abs_tol)
+        return report, off
 
 
 class MiniappMixedAdapter:
@@ -670,11 +688,16 @@ class ArchAdapter:
 
 
 def resolve_adapter(spec: OffloadSpec,
-                    hw: Optional[ev.HardwareModel] = None):
+                    hw: Optional[ev.HardwareModel] = None, run_fn=None):
+    """The adapter of a spec; ``run_fn`` (measured fidelity only) is the
+    caller's run fn in place of ``MEASURED_RUN_FNS``'s row."""
+    if run_fn is not None and spec.fidelity != "measured":
+        raise ValueError("a caller's run_fn is what measured fidelity "
+                         f"wall-clocks; this spec is {spec.fidelity!r}")
     if spec.is_arch:
         return ArchAdapter(spec, hw)
     if spec.fidelity == "measured":
-        return MiniappMeasuredAdapter(spec, hw)
+        return MiniappMeasuredAdapter(spec, hw, run_fn)
     if spec.mode == "mixed":
         return MiniappMixedAdapter(spec, hw)
     return MiniappBinaryAdapter(spec, hw)

@@ -88,6 +88,12 @@ class OffloadResult:
     # job is non-terminal". Additive: None for every non-service run,
     # keeping those artifact bytes identical to pre-serving ones.
     job: Optional[Dict[str, Any]] = None
+    # the winner's answer at measured fidelity: the output of its placed
+    # run that verify's PCAST compared against the host path, at the
+    # measured scale. In memory only: never saved, loaded or traced, and
+    # left out of equality.
+    answer: Optional[Dict[str, Any]] = dataclasses.field(
+        default=None, compare=False, repr=False)
 
     # -- stage bookkeeping --------------------------------------------------
 

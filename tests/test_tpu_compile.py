@@ -65,16 +65,23 @@ def _compile(fn, *args):
 def test_himeno_sweep_compiles_for_v5e(shape):
     """The measured path's jitted Jacobi sweep at the grid it times."""
     grid = programs.measured_run_fn("himeno", "model").grid
-    assert grid == (128, 128, 256)
+    assert grid == (129, 129, 257)  # Himeno size M, as published
     f32 = jnp.float32
     compiled = _compile(
         miniapps._himeno_sweep_jit(),
         shape(grid, f32), shape((4,) + grid, f32), shape((3,) + grid, f32),
         shape((3,) + grid, f32), shape(grid, f32), shape(grid, f32),
     )
-    # p, a, b, c, bnd, wrk1: 13 float32 planes of 128x128x256
+    # p, a, b, c, bnd, wrk1: 13 float32 planes of 129x129x257, each laid
+    # out in (8, 128) tiles of its two minor axes (129 -> 136 rows, 257 ->
+    # 384 lanes), except the four planes of a, which the compiler lays out
+    # with the plane axis second-minor, in (4, 128) tiles of (plane, k)
+    i, j, k = grid
+    tiled_k = -(-k // 128) * 128
+    planes_8x128 = 9 * i * (-(-j // 8) * 8) * tiled_k
+    a_4x128 = i * j * 4 * tiled_k
     assert compiled.memory_analysis().argument_size_in_bytes == \
-        13 * 4 * 128 * 128 * 256
+        4 * (planes_8x128 + a_4x128)
 
 
 def test_nasft_step_compiles_for_v5e(shape):
