@@ -594,6 +594,75 @@ def _nasft_checksum_jit():
     return fn
 
 
+def _nasft_fft_jit():
+    fn = _JITTED.get("nasft_fft")
+    if fn is None:
+        import jax
+        import jax.numpy as jnp
+
+        @jax.jit
+        def fft(re, im):
+            # u0 is assembled from its two float32 planes here, so the
+            # complex64 field exists only on the device
+            return jnp.fft.fftn(jax.lax.complex(re, im))
+
+        _JITTED["nasft_fft"] = fn = fft
+    return fn
+
+
+def _nasft_k2_jit():
+    """The wavenumbers' squared norm, built on the device in float32.
+
+    With every side n a power of two, each wavenumber is an integer
+    times 1/n, and each |k|^2 an integer of at most 3 N^2 / 4 over N^2
+    (N the longest side): up to N = 4096 float32 holds every term and
+    every sum exactly, so this is bit for bit the host's float64 build
+    (``_nasft_k2_host``) rounded to float32. On any other grid the two
+    can differ by a float32 rounding (about 6e-8), far inside the
+    float32 tolerance the jitted path is held to."""
+    fn = _JITTED.get("nasft_k2")
+    if fn is None:
+        import jax
+        import jax.numpy as jnp
+
+        def wavenumbers(n):
+            # fftfreq(n) in float32, scaled by an exact power of two
+            i = jnp.arange(n, dtype=jnp.int32)
+            return ((i + n // 2) % n - n // 2).astype(jnp.float32) * (1.0 / n)
+
+        def k2(shape):
+            nz, ny, nx = shape
+            kz = wavenumbers(nz)[:, None, None]
+            ky = wavenumbers(ny)[None, :, None]
+            kx = wavenumbers(nx)[None, None, :]
+            return kx**2 + ky**2 + kz**2
+
+        _JITTED["nasft_k2"] = fn = jax.jit(k2, static_argnums=0)
+    return fn
+
+
+def _nasft_k2_host(shape: Tuple[int, int, int]) -> np.ndarray:
+    nz, ny, nx = shape
+    kz = np.fft.fftfreq(nz)[:, None, None]
+    ky = np.fft.fftfreq(ny)[None, :, None]
+    kx = np.fft.fftfreq(nx)[None, None, :]
+    return (kx**2 + ky**2 + kz**2).astype(np.float32)
+
+
+def _nasft_planes(shape: Tuple[int, int, int]):
+    """The initial field's real, then imaginary, plane: numpy
+    ``default_rng(314159)`` normals drawn into one reused float64 buffer
+    and cast to float32, bit for bit ``.real`` and ``.imag`` of
+    ``(a + 1j * b).astype(np.complex64)`` with no complex temporaries.
+    A generator, so that the caller can hand the first plane on before
+    the second is drawn."""
+    rng = np.random.default_rng(314159)
+    buf = np.empty(shape)
+    for _ in range(2):
+        rng.standard_normal(out=buf)
+        yield buf.astype(np.float32)
+
+
 def nasft_run(
     grid: Tuple[int, int, int] = (16, 16, 16),
     niter: int = 2,
@@ -604,26 +673,24 @@ def nasft_run(
     Returns the per-iteration checksums (complex64 ndarray, shape (niter,)).
     ``jit_fft`` switches the FFT+evolve between jitted JAX and numpy.
 
-    On the jitted path each checksum is reduced on the device from that
-    iteration's ``u1``, and only the ``niter`` sums come back to the host,
-    in one fetch after the loop.
+    On the jitted path ``u0`` goes to the device as two float32 planes,
+    each put as soon as it is drawn (so that the host's handling of the
+    first overlaps the draw of the second), and is assembled and
+    transformed there; ``k2`` is built on the device
+    (:func:`_nasft_k2_jit`). Each checksum is reduced on the device from
+    that iteration's ``u1``, and only the ``niter`` sums come back to the
+    host, in one fetch after the loop.
 
-    Host steps run in spans as in :func:`himeno_run`: ``nasft.init``,
-    then on the jitted path ``nasft.copy_in``, ``nasft.fft`` (dispatch),
-    per iteration ``nasft.step`` and ``nasft.checksum`` (dispatches), and
-    one ``nasft.copy_out`` (the fetch of the sums)."""
+    Host steps run in spans as in :func:`himeno_run`. On the jitted path:
+    per plane a ``nasft.init`` (draw) and a ``nasft.copy_in`` (put), one
+    ``nasft.fft`` (the dispatches of the ``k2`` build and of the assembly
+    and FFT), per iteration ``nasft.step`` and ``nasft.checksum``
+    (dispatches), and one ``nasft.copy_out`` (the fetch of the sums)."""
     import jax.numpy as jnp
     from jax.profiler import TraceAnnotation as span
 
     nx, ny, nz = grid
-    with span("nasft.init"):
-        rng = np.random.default_rng(314159)
-        u0 = (rng.standard_normal((nz, ny, nx)) +
-              1j * rng.standard_normal((nz, ny, nx))).astype(np.complex64)
-        kz = np.fft.fftfreq(nz)[:, None, None]
-        ky = np.fft.fftfreq(ny)[None, :, None]
-        kx = np.fft.fftfreq(nx)[None, None, :]
-        k2 = (kx**2 + ky**2 + kz**2).astype(np.float32)
+    shape = (nz, ny, nx)
     alpha = 1e-2
 
     def checksum(u1):
@@ -633,12 +700,20 @@ def nasft_run(
 
     if jit_fft:
         step = _nasft_step_jit()
+        planes = _nasft_planes(shape)
+        with span("nasft.init"):
+            re = next(planes)
         with span("nasft.copy_in"):
-            u0j = jnp.asarray(u0)
-            k2j = jnp.asarray(k2)
+            re = jnp.asarray(re)
+        with span("nasft.init"):
+            im = next(planes)
+            planes.close()  # frees the float64 buffer
+        with span("nasft.copy_in"):
+            im = jnp.asarray(im)
         with span("nasft.fft"):
-            ut = jnp.fft.fftn(u0j)
-        del u0j  # free u0 on the chip once the FFT has read it
+            k2j = _nasft_k2_jit()(shape)
+            ut = _nasft_fft_jit()(re, im)
+        del re, im  # free the planes on the chip once the FFT has read them
         device_checksum = _nasft_checksum_jit()
         sums = []
         for it in range(1, niter + 1):
@@ -650,6 +725,10 @@ def nasft_run(
         with span("nasft.copy_out"):
             return np.asarray(jnp.stack(sums), np.complex64)
 
+    with span("nasft.init"):
+        u0 = np.empty(shape, np.complex64)
+        u0.real, u0.imag = _nasft_planes(shape)
+        k2 = _nasft_k2_host(shape)
     ut = np.fft.fftn(u0)
     sums = []
     for it in range(1, niter + 1):
@@ -754,9 +833,10 @@ class NasftRunFn:
     @property
     def tag(self) -> str:
         """See :meth:`HimenoRunFn.tag`; ``chk-dev`` names the placement
-        that sums the checksums on the device."""
+        that sums the checksums on the device, ``u0-dev`` the one that
+        assembles ``u0`` and builds ``k2`` there."""
         return (f"nasft:{'x'.join(map(str, self.grid))}:it{self.niter}"
-                ":chk-dev")
+                ":chk-dev:u0-dev")
 
     def program(self) -> LoopProgram:
         return nasft_program(grid=self.grid, niter=self.niter)

@@ -96,6 +96,29 @@ def test_nasft_step_compiles_for_v5e(shape):
     assert "fft" in compiled.as_text().lower()
 
 
+def test_nasft_fft_compiles_for_v5e(shape):
+    """The class A field assembled from its two float32 planes and
+    transformed: one complex64 field of temp beyond the output at most."""
+    nx, ny, nz = programs.measured_run_fn("nasft", "model").grid
+    compiled = _compile(miniapps._nasft_fft_jit(),
+                        shape((nz, ny, nx), jnp.float32),
+                        shape((nz, ny, nx), jnp.float32))
+    assert "fft" in compiled.as_text().lower()
+    assert compiled.memory_analysis().temp_size_in_bytes <= nx * ny * nz * 8
+
+
+def test_nasft_k2_compiles_for_v5e(topo):
+    """The class A wavenumbers built on the chip from nothing but the
+    grid: no argument, and one float32 field out."""
+    nx, ny, nz = programs.measured_run_fn("nasft", "model").grid
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    compiled = jax.jit(lambda: miniapps._nasft_k2_jit()((nz, ny, nx)),
+                       out_shardings=one_chip).lower().compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == 0
+    assert mem.output_size_in_bytes == nx * ny * nz * 4
+
+
 def test_nasft_checksum_compiles_for_v5e(shape):
     """The device checksum gathers its 1024 samples from the class A
     field in place: no copy of the field."""
