@@ -12,7 +12,7 @@ implementation instead of placing the loops individually.
   dimension, priced through a fused-nest variant program.
 
 Enabled per run via ``OffloadSpec.blocks`` (mixed mode only; off =
-byte-identical to the loop-level search). See docs/blocks.md.
+the loop-level search). See docs/blocks.md.
 """
 from repro.blocks.library import (  # noqa: F401
     BlockSignature,

@@ -21,7 +21,7 @@ decides the measured scale in one table).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, ClassVar, Dict, Sequence, Tuple
 
 import numpy as np
 
@@ -758,19 +758,48 @@ def _gene_index(prog: LoopProgram, loop_name: str) -> int:
 _HOT_GENES: Dict[Tuple[str, str], int] = {}
 
 
-def _hot_gene(prog_fn, loop_name: str) -> int:
-    """Memoized gene index of a program's hot loop: run fns sit inside
-    MeasuredEvaluator's perf_counter window, so the LoopProgram must not
-    be rebuilt per measurement."""
-    key = (prog_fn.__name__, loop_name)
-    if key not in _HOT_GENES:
-        _HOT_GENES[key] = _gene_index(prog_fn(), loop_name)
-    return _HOT_GENES[key]
+class _RunFn:
+    """What both run fns share. ``app`` names the program in
+    :data:`MINIAPPS`, and ``hot_loop`` the one loop whose gene picks the
+    path: jitted JAX (offloaded) or numpy (host)."""
+
+    app: ClassVar[str]
+    hot_loop: ClassVar[str]
+
+    def run(self, offloaded: bool) -> Dict[str, Any]:
+        raise NotImplementedError
+
+    def pair(self, offloaded: bool) -> Tuple[Dict[str, Any], Dict[str, Any]]:
+        """(host reference, placement under test) for PCAST."""
+        return self.run(False), self.run(offloaded)
+
+    @property
+    def hot_gene(self) -> int:
+        """Memoized gene index of the hot loop: run fns sit inside
+        MeasuredEvaluator's perf_counter window, so the LoopProgram must
+        not be rebuilt per measurement."""
+        key = (self.app, self.hot_loop)
+        if key not in _HOT_GENES:
+            _HOT_GENES[key] = _gene_index(MINIAPPS[self.app](), self.hot_loop)
+        return _HOT_GENES[key]
+
+    def __call__(self, genes: Sequence[int]) -> None:
+        self.run(bool(genes[self.hot_gene]))
+
+    def cache_key(self, genes: Sequence[int]) -> str:
+        """Canonical measurement key: the implementation only branches on
+        the hot-loop gene, so genomes equal there run the *same*
+        computation and share one wall-clock measurement (generation
+        dedup + the persistent cache both collapse on this)."""
+        return f"hot={int(bool(genes[self.hot_gene]))}"
 
 
 @dataclasses.dataclass(frozen=True)
-class HimenoRunFn:
-    """genes -> run Himeno; the ``jacobi_stencil`` gene picks the path."""
+class HimenoRunFn(_RunFn):
+    """genes -> run Himeno; the hot loop's gene picks the path."""
+
+    app: ClassVar[str] = "himeno"
+    hot_loop: ClassVar[str] = "jacobi_stencil"
 
     grid: Tuple[int, int, int]
     nn: int
@@ -780,21 +809,6 @@ class HimenoRunFn:
         outputs PCAST compares."""
         p, gosa = himeno_run(self.grid, self.nn, jit_stencil=offloaded)
         return {"p": p, "gosa": np.float32(gosa)}
-
-    def pair(self, offloaded: bool) -> Tuple[Dict[str, Any], Dict[str, Any]]:
-        """(host reference, placement under test) for PCAST."""
-        return self.run(False), self.run(offloaded)
-
-    def __call__(self, genes: Sequence[int]) -> None:
-        self.run(bool(genes[_hot_gene(himeno_program, "jacobi_stencil")]))
-
-    def cache_key(self, genes: Sequence[int]) -> str:
-        """Canonical measurement key: the implementation only branches on
-        the hot-loop gene, so genomes equal there run the *same*
-        computation and share one wall-clock measurement (generation
-        dedup + the persistent cache both collapse on this)."""
-        hot = _hot_gene(himeno_program, "jacobi_stencil")
-        return f"hot={int(bool(genes[hot]))}"
 
     @property
     def tag(self) -> str:
@@ -808,8 +822,11 @@ class HimenoRunFn:
 
 
 @dataclasses.dataclass(frozen=True)
-class NasftRunFn:
-    """genes -> run NAS.FT; the ``evolve`` gene picks the path."""
+class NasftRunFn(_RunFn):
+    """genes -> run NAS.FT; the hot loop's gene picks the path."""
+
+    app: ClassVar[str] = "nasft"
+    hot_loop: ClassVar[str] = "evolve"
 
     grid: Tuple[int, int, int]
     niter: int
@@ -817,18 +834,6 @@ class NasftRunFn:
     def run(self, offloaded: bool) -> Dict[str, Any]:
         return {"checksums": nasft_run(self.grid, self.niter,
                                        jit_fft=offloaded)}
-
-    def pair(self, offloaded: bool) -> Tuple[Dict[str, Any], Dict[str, Any]]:
-        """See :meth:`HimenoRunFn.pair`."""
-        return self.run(False), self.run(offloaded)
-
-    def __call__(self, genes: Sequence[int]) -> None:
-        self.run(bool(genes[_hot_gene(nasft_program, "evolve")]))
-
-    def cache_key(self, genes: Sequence[int]) -> str:
-        """See :meth:`HimenoRunFn.cache_key`."""
-        hot = _hot_gene(nasft_program, "evolve")
-        return f"hot={int(bool(genes[hot]))}"
 
     @property
     def tag(self) -> str:

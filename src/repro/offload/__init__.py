@@ -31,8 +31,8 @@ This package is that sequence as a reusable surface:
   touch it).
 
 Every example, benchmark and calibration script drives this facade; with
-spec defaults its searches are byte-identical to the pre-redesign
-hand-wired paths (parity-tested).
+spec defaults its searches match a hand-wired ``run_ga`` over the same
+evaluator (parity-tested in tests/test_offload_pipeline.py).
 """
 from repro.offload.pipeline import Offloader, render_report
 from repro.offload.result import (

@@ -170,8 +170,7 @@ def _add_spec_args(p: argparse.ArgumentParser) -> None:
                    help="PCAST absolute tolerance override")
     p.add_argument("--diversity", type=float, default=None,
                    help="fitness-sharing strength for GA selection "
-                        "(default 0 = off, byte-identical to the "
-                        "historical selection)")
+                        "(default 0 = off: plain roulette selection)")
     p.add_argument("--stability-seeds", type=int, default=None,
                    metavar="K",
                    help="pass@k winner-stability seeds re-searched by "

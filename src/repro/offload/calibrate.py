@@ -112,9 +112,7 @@ def _probe_run_fn(p: Probe):
 
 
 def _probe_program(p: Probe) -> LoopProgram:
-    if p.app == "himeno":
-        return miniapps.himeno_program(grid=p.grid, nn=p.steps)
-    return miniapps.nasft_program(grid=p.grid, niter=p.steps)
+    return _probe_run_fn(p).program()
 
 
 def _region_quantities(prog: LoopProgram) -> Tuple[float, float, float]:
@@ -143,7 +141,7 @@ def _measure_probe(p: Probe, repeats: int) -> float:
     n = miniapps.MINIAPPS[p.app]().gene_length
     genes = [0] * n
     if p.dest == "accel":
-        genes[programs.hot_gene_index(p.app)] = 1
+        genes[fn.hot_gene] = 1
     fn(genes)  # warm-up (compile cache), not timed
     return float(m(genes))
 

@@ -31,10 +31,10 @@ where it stopped. A stage failure is recorded (status ``failed``) and
 saved *before* :class:`StageFailure` propagates, so the artifact always
 reflects what actually happened.
 
-With ``spec`` defaults, the facade's searches are byte-identical to the
-pre-redesign hand-wired paths (parity-tested in
-tests/test_offload_pipeline.py): same GAParams, same pool construction,
-same RNG stream.
+The stages reach the program only through its adapter
+(:class:`repro.offload.programs.ProgramAdapter`): the adapter builds the
+evaluators, checks a placement with PCAST, and projects a genome onto
+the one placement a runnable program can really run.
 """
 from __future__ import annotations
 
@@ -187,8 +187,8 @@ class Offloader:
         :class:`~repro.core.evalpool.EvalBroker` view opener here so
         concurrent jobs share one in-memory store; the stage still calls
         ``close()`` on what it gets back, so factories must hand out
-        refcounted views. ``None`` (the default) keeps single-run
-        behavior byte-identical to the pre-serving pipeline.
+        refcounted views. ``None`` (the default) opens ``spec.cache``
+        itself.
     run_fn:
         fidelity="measured" only: a run fn of the caller's (the shape of
         ``programs.MEASURED_RUN_FNS``'s rows) in place of the spec's
@@ -310,14 +310,6 @@ class Offloader:
         calibrate.install(cal, replace=True)
         self._cal = cal
         return cal
-
-    def _measured_run_fn(self):
-        """The run fn this run's clocks and measured PCAST check use: the
-        caller's, else the spec's measured-scale row."""
-        if self._run_fn is not None:
-            return self._run_fn
-        return programs.measured_run_fn(self.spec.program,
-                                        self.spec.measured_scale)
 
     def _search_evaluator(self):
         return self._evaluator if self._evaluator is not None \
@@ -565,12 +557,7 @@ class Offloader:
             best_genes = [int(g) for g in res.best_genes]
             best_t: Optional[float] = float(res.best_time_s)
             placement = adapter.placement(res.best_genes)
-            stats_fn = getattr(adapter, "schedule_stats", None)
-            residency = stats_fn(res.best_genes) if stats_fn is not None \
-                else None
-            subs_fn = getattr(adapter, "substitutions", None)
-            substitutions = subs_fn(res.best_genes) \
-                if subs_fn is not None else None
+            extras = adapter.search_extras(res.best_genes)
             last = res.history[-1]
             final_population = [[int(g) for g in ind]
                                 for ind in (last.population or [])]
@@ -578,15 +565,12 @@ class Offloader:
         else:
             # a zero-generation budget evaluates nothing: record an
             # explicit no-winner search instead of a fake one
-            best_genes, best_t, placement, residency = [], None, {}, None
+            best_genes, best_t, placement, extras = [], None, {}, {}
             final_population, final_times = [], []
-            substitutions = None
         return {
             "best_genes": best_genes,
             "best_time_s": best_t,
-            **({"residency": residency} if residency is not None else {}),
-            **({"substitutions": substitutions}
-               if substitutions is not None else {}),
+            **extras,
             "wall_s": float(res.wall_s),
             "evaluations": int(tot.evaluated),
             "cache_hits": int(tot.cache_hits),
@@ -600,15 +584,12 @@ class Offloader:
                 "population": params.population,
                 "generations": params.generations,
                 "alleles": params.alleles,
-                "allele_names": list(getattr(adapter, "allele_names",
-                                             ()) or ()),
+                "allele_names": list(adapter.allele_names),
                 "seed": params.seed,
                 "seeded": len(seeds),
                 "diversity": float(params.diversity),
-                # recorded only when on: knobs-off payloads stay
-                # byte-identical to pre-fast-search artifacts
-                **({"steady_state": True} if params.steady_state else {}),
-                **({"batch": True} if self.spec.ga.batch else {}),
+                "steady_state": bool(params.steady_state),
+                "batch": bool(self.spec.ga.batch),
             },
             "placement": placement,
             "history": [
@@ -683,13 +664,11 @@ class Offloader:
                 "mismatch_rel": mismatch,
                 "consistent": bool(consistent),
             }
-        answer_fn = getattr(adapter, "pcast_answer", None)
-        if answer_fn is not None:
+        report, answer = adapter.verify(best)
+        if answer is not None:
             # measured fidelity: keep what the winner's placed run
             # produced, in memory only (never in the payload or trace)
-            report, self.result.answer = answer_fn(best)
-        else:
-            report = adapter.pcast_check(best)
+            self.result.answer = answer
         if report is None:
             payload["pcast"] = {
                 "skipped": "no runnable reference implementation",
@@ -704,7 +683,7 @@ class Offloader:
         fid = self._fidelity_section(best, best_t)
         if fid is not None:
             payload["fidelity"] = fid
-        oracles = self._block_oracles(adapter, best)
+        oracles = self._block_oracles(best)
         if oracles is not None:
             payload["block_oracles"] = oracles
         if not consistent:
@@ -730,17 +709,15 @@ class Offloader:
             )
         return payload
 
-    def _block_oracles(self, adapter, best) -> Optional[list]:
+    def _block_oracles(self, best) -> Optional[list]:
         """Kernel-oracle checks for every substitution the winner
         activates: the substituted implementation (the real kernel,
         compiled on a TPU and interpreted elsewhere) vs its
         ``kernels/ref.py`` oracle on a tiny
         seeded input — the block analogue of the PCAST placement check.
-        None when the run has no block genome (blocks-off byte parity)."""
-        subs_fn = getattr(adapter, "substitutions", None)
-        if subs_fn is None:
-            return None
-        subs = subs_fn(best)
+        None when the run has no block genome."""
+        adapter = self.adapter
+        subs = adapter.search_extras(best).get("substitutions")
         if subs is None:
             return None
         from repro import blocks as blocks_mod
@@ -765,51 +742,11 @@ class Offloader:
                 })
         return rows
 
-    def _scale_model(self) -> Callable[[Sequence[int]], float]:
-        """The analytic model of the effective spec's machine AT THE
-        MEASURED SCALE — what fidelity/rank sections compare real wall
-        clocks against (a paper-scale prediction would be off by the
-        problem-size ratio, not by model error)."""
-        from repro.core import evaluator as ev
-        from repro.core import transfer as tr
-
-        spec = self.spec
-        if spec.fidelity == "measured":
-            return self.adapter.model_evaluator()
-        eff = self._effective_spec()
-        scale_prog = programs.measured_run_fn(
-            spec.program, spec.measured_scale).program()
-        if spec.mode == "mixed":
-            from repro.destinations import MixedEvaluator, get_registry
-
-            reg = get_registry(eff.hw)
-            if getattr(self.adapter, "matches", ()):
-                # block-enabled genomes carry block genes; price them
-                # with a block evaluator over the scale program (same
-                # loop structure -> same matches)
-                from repro.blocks import BlockMixedEvaluator
-
-                return BlockMixedEvaluator(
-                    scale_prog, eff.destinations, registry=reg,
-                    library=self.adapter.library,
-                )
-            return MixedEvaluator(scale_prog, eff.destinations,
-                                  registry=reg)
-        method = programs.METHODS[eff.method]
-        return ev.MiniappEvaluator(
-            scale_prog,
-            tr.TransferMode(method["transfer"]),
-            staged=method["staged"],
-            hw=programs.resolve_hw(eff),
-            kernels_only=method["kernels_only"],
-        )
-
     def _fidelity_section(self, best, best_t: float) -> Optional[Dict]:
         """Predicted-vs-measured honesty check of the winner (and the
         all-host baseline), one row per destination involved. Modeled
-        runs skip it (nothing was measured, and the pipeline must stay
-        byte-identical to the pre-fidelity artifacts); programs without
-        a runnable implementation record why.
+        runs skip it (nothing was measured); programs without a runnable
+        implementation record why.
 
         - fidelity="measured": predicted comes from the analytic model
           of the spec's machine AT THE MEASURED SCALE; measured numbers
@@ -817,26 +754,25 @@ class Offloader:
         - fidelity="calibrated": predicted comes from the calibrated
           model at the measured scale; the winner and baseline are
           freshly wall-clocked in-process.
-        """
-        from repro.core import evaluator as ev
-        from repro.offload.spec import MEASURED_PROGRAMS
 
+        The winner row compares the model and the clock on the winner's
+        realised projection (``ProgramAdapter.realised``): anything else
+        would price loops (or backends, for k-ary genomes: the run fn
+        jits for ANY nonzero allele) the measurement cannot move.
+        """
         spec = self.spec
         if spec.fidelity == "modeled":
             return None
-        if spec.program not in MEASURED_PROGRAMS:
+        adapter = self.adapter
+        if adapter.run_fn is None:
             return {
                 "level": spec.fidelity,
                 "skipped": "no runnable implementation to measure "
                            "(calibration residuals still recorded in the "
                            "calibrate stage)",
             }
-        adapter = self.adapter
-        n = adapter.gene_length
-        zeros = (0,) * n
-        run_fn = self._measured_run_fn()
-        model = self._scale_model()
-
+        zeros = (0,) * adapter.gene_length
+        model = adapter.scale_model()
         if spec.fidelity == "measured":
             reference = f"model:{adapter.hw.name}"
             meas_host = float(
@@ -845,27 +781,9 @@ class Offloader:
             meas_win = float(best_t)
         else:  # calibrated
             reference = f"calibrated:{self._ensure_calibration().hw_name}"
-            m = ev.MeasuredEvaluator(run_fn, repeats=spec.repeats,
-                                     tag=run_fn.tag)
+            m = adapter.clock()
             meas_host = float(m(zeros))
             meas_win = float(m(best))
-
-        # the runnable implementations realize exactly ONE placement
-        # switch (the hot loop on the generic jit/accelerator path), so
-        # the winner row compares the model and the clock on the
-        # REALIZABLE projection of the winner — anything else would
-        # price loops (or backends, for k-ary genomes: the run_fn jits
-        # for ANY nonzero allele) the measurement cannot move
-        hot = programs.hot_gene_index(spec.program)
-        hot_name = programs.RUNNABLE[spec.program][0]
-        host = "cpu"
-        hot_offloaded = adapter.placement(best).get(hot_name, host) != host
-        if spec.mode == "mixed":
-            dests = adapter.build_evaluator().dests
-            accel = next((i for i, d in enumerate(dests)
-                          if d.kind in ("gpu", "tpu")), None)
-        else:
-            dests, accel = None, 1
 
         def row(dest: str, label: str, pred: float, meas: float) -> Dict:
             return {
@@ -876,8 +794,9 @@ class Offloader:
                 "ratio": float(pred / meas) if meas > 0 else float("inf"),
             }
 
-        rows = [row(host, "all-host", model(zeros), meas_host)]
-        if hot_offloaded and accel is None:
+        rows = [row(adapter.host, "all-host", model(zeros), meas_host)]
+        real = adapter.realised(best)
+        if real.destination is None:
             # e.g. a cpu+fpga subset: the jit path the clock runs has no
             # counterpart destination in the model — say so, don't fake it
             rows.append({
@@ -887,17 +806,11 @@ class Offloader:
                            "destination matching the jit measurement",
             })
         else:
-            allele = accel if hot_offloaded else 0
-            realized = tuple(
-                allele if i == hot else 0 for i in range(n)
-            )
-            win_dest = dests[allele].name if dests is not None \
-                else ("gpu" if allele else host)
-            rows.append(row(win_dest, "winner:hot-loop",
-                            model(realized), meas_win))
+            rows.append(row(real.destination, "winner:hot-loop",
+                            model(real.genes), meas_win))
         return {
             "level": spec.fidelity,
-            "scale": run_fn.tag,
+            "scale": adapter.run_fn.tag,
             "reference": reference,
             "rows": rows,
         }
@@ -943,12 +856,11 @@ class Offloader:
                                "arbitrarily expensive; call "
                                "quality.winner_stability directly)"}
         adapter = self.adapter
-        # re-searches always run the cheap MODELED evaluator: for
+        # re-searches always run a cheap deterministic evaluator: for
         # fidelity="measured" that is the analytic model at measured
         # scale, not the wall-clocking run_fn
-        model_fn = getattr(adapter, "model_evaluator", None)
-        evaluator = model_fn() if callable(model_fn) \
-            else self._search_evaluator()
+        evaluator = adapter.build_evaluator() if adapter.deterministic \
+            else adapter.scale_model()
         fp = evaluator_fingerprint(evaluator)
         recorded = None
         if search.get("evaluator") == fp \
@@ -987,9 +899,6 @@ class Offloader:
         return st
 
     def _rank_section(self, search: Dict[str, Any]) -> Dict[str, Any]:
-        from repro.core import evaluator as ev
-        from repro.offload.spec import MEASURED_PROGRAMS
-
         spec = self.spec
         knobs = spec.ga
         final = search.get("final_population") or []
@@ -998,7 +907,8 @@ class Offloader:
             return {"skipped": "no final population recorded "
                                "(zero generations, or an artifact from "
                                "before tracing)"}
-        if spec.is_arch or spec.program not in MEASURED_PROGRAMS:
+        adapter = self.adapter
+        if adapter.run_fn is None:
             return {"skipped": "no runnable implementation to measure "
                                "against"}
         if self._evaluator is not None:
@@ -1006,10 +916,7 @@ class Offloader:
         if spec.fidelity != "measured" and not knobs.rank_probe:
             return {"skipped": "rank probe off (ga.rank_probe=false; "
                                "measured fidelity ranks for free)"}
-        adapter = self.adapter
-        n = adapter.gene_length
-        run_fn = self._measured_run_fn()
-        model = self._scale_model()
+        model = adapter.scale_model()
         pop = [tuple(int(g) for g in ind) for ind in final]
         modeled = [float(model(g)) for g in pop]
         tracer = self._trace()
@@ -1025,46 +932,28 @@ class Offloader:
             # runnable implementations realize exactly one placement
             # switch (hot loop on the jit path or not), so measurement
             # can only ever distinguish those two classes
-            hot = programs.hot_gene_index(spec.program)
-            hot_name = programs.RUNNABLE[spec.program][0]
-            host = "cpu"
-            if spec.mode == "mixed":
-                dests = adapter.build_evaluator().dests
-                accel = next((i for i, d in enumerate(dests)
-                              if d.kind in ("gpu", "tpu")), None)
-            else:
-                accel = 1
-            m = ev.MeasuredEvaluator(run_fn, repeats=spec.repeats,
-                                     tag=run_fn.tag)
-            zeros = (0,) * n
-            t_host = float(m(zeros))
+            m = adapter.clock()
+            t_host = float(m((0,) * adapter.gene_length))
             if tracer is not None:
                 tracer.event("rank_probe", span="report", attrs={
                     "projection": "all-host", "evaluations": 1,
                     "measured_s": t_host,
                 })
-            offloaded = [
-                adapter.placement(g).get(hot_name, host) != host
-                for g in pop
-            ]
+            real = [adapter.realised(g) for g in pop]
+            on = next((r.genes for r in real if r.offloaded), None)
             t_off = None
-            if any(offloaded):
-                on_genome = tuple(
-                    (accel if accel is not None else 1) if i == hot else 0
-                    for i in range(n)
-                )
-                t_off = float(m(on_genome))
+            if on is not None:
+                t_off = float(m(on))
                 if tracer is not None:
                     tracer.event("rank_probe", span="report", attrs={
                         "projection": "hot-offloaded", "evaluations": 1,
                         "measured_s": t_off,
                     })
-            measured = [t_off if off else t_host for off in offloaded]
-        eff = self._effective_spec()
+            measured = [t_off if r.offloaded else t_host for r in real]
         return qual.rank_section(
             modeled, measured,
-            scale=run_fn.tag,
-            reference=f"model:{eff.hw}",
+            scale=adapter.run_fn.tag,
+            reference=f"model:{self._effective_spec().hw}",
         )
 
 

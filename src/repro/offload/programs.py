@@ -13,15 +13,16 @@ A *program* is whatever the offload genome indexes into:
   ExecutionPlan, scored by the analytic plan evaluator (or an injected
   ``CompiledEvaluator`` for real AOT-compile scoring).
 
-Each adapter knows its gene length and allele count, builds its
-evaluator, computes the all-host baseline, renders a genome as a
-{unit: destination} placement, and (for miniapps with runnable JAX
-implementations) produces the PCAST result-difference check of the
-offloaded path against the CPU reference.
+Every adapter implements :class:`ProgramAdapter`, the questions the
+Offloader's stages ask: gene length and alleles, the search evaluator,
+the all-host baseline, a genome's {unit: destination} placement, and —
+for miniapps with runnable JAX implementations — the PCAST check of the
+offloaded path against the CPU reference, the model at the measured
+scale, and the one placement the runnable program can really run.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence, Tuple
 
 from repro.core import evaluator as ev
 from repro.core import miniapps
@@ -83,7 +84,7 @@ def resolve_hw(spec: OffloadSpec,
 
 
 # ---------------------------------------------------------------------------
-# runnable programs: hot loop, run fns, PCAST pairs
+# runnable programs: run fns at each scale, hot loops, PCAST pairs
 # ---------------------------------------------------------------------------
 
 # The measured scale, in one table: the run fn (grid + iteration count)
@@ -105,15 +106,19 @@ MEASURED_RUN_FNS: Dict[str, Dict[str, Any]] = {
     },
 }
 
-# miniapp name -> (hot loop whose gene selects the accelerator path,
-# PCAST pair builder of the MODELED adapters: a correctness check of the
-# implementation at a small grid, since nothing is timed there). Apps
-# absent here have no runnable implementation; their verify stage
+# The run fns of the MODELED adapters' PCAST check: a correctness check
+# of the implementation at a small grid, since nothing is timed there.
+# Apps absent here have no runnable implementation; their verify stage
 # records the PCAST check as skipped.
+_PCAST_RUN_FNS: Dict[str, Any] = {
+    "himeno": miniapps.HimenoRunFn(grid=(17, 17, 33), nn=4),
+    "nasft": miniapps.NasftRunFn(grid=(16, 16, 16), niter=2),
+}
+
+# miniapp name -> (hot loop whose gene selects the accelerator path,
+# PCAST pair of the modeled adapters), read off the run fns
 RUNNABLE: Dict[str, Tuple[str, Callable[[bool], Tuple[Any, Any]]]] = {
-    "himeno": ("jacobi_stencil",
-               miniapps.HimenoRunFn(grid=(17, 17, 33), nn=4).pair),
-    "nasft": ("evolve", miniapps.NasftRunFn(grid=(16, 16, 16), niter=2).pair),
+    name: (fn.hot_loop, fn.pair) for name, fn in _PCAST_RUN_FNS.items()
 }
 
 assert set(MEASURED_RUN_FNS) == set(MEASURED_SCALES), \
@@ -131,8 +136,7 @@ def measured_run_fn(program: str, scale: str):
 def hot_gene_index(name: str) -> int:
     """Gene index of the runnable implementation's hot loop — the one
     gene the measured path actually realizes (docs/fidelity.md)."""
-    prog = miniapps.MINIAPPS[name]()
-    return miniapps._gene_index(prog, RUNNABLE[name][0])
+    return _PCAST_RUN_FNS[name].hot_gene
 
 
 def _pcast(pair: Callable[[bool], Tuple[Any, Any]], offloaded: bool,
@@ -145,16 +149,122 @@ def _pcast(pair: Callable[[bool], Tuple[Any, Any]], offloaded: bool,
                          abs_tol=spec.abs_tol)
 
 
+def _loop_rows(prog: LoopProgram) -> list:
+    """The analyze payload's per-loop rows: class and paper directive."""
+    return [
+        {
+            "name": l.name,
+            "class": l.klass.value,
+            "directive": DIRECTIVES[l.klass],
+            "offloadable": l.offloadable,
+        }
+        for l in prog.loops
+    ]
+
+
 # ---------------------------------------------------------------------------
 # adapters
 # ---------------------------------------------------------------------------
 
 
-class MiniappBinaryAdapter:
+class Realised(NamedTuple):
+    """A genome as the runnable program really runs it. The runnable
+    implementations realize exactly ONE placement switch (the hot loop on
+    the jit path or not), so ``genes`` sets the hot loop's gene to the
+    accelerator allele when the genome offloads that loop (0 when not)
+    and every other gene to 0. ``destination`` is where the model prices
+    that run: None when the hot loop is offloaded but the searched
+    destinations have no gpu/tpu-kind one matching the jit path (the
+    genes then carry allele 1, which the run fn reads as offloaded)."""
+
+    genes: Tuple[int, ...]
+    offloaded: bool
+    destination: Optional[str]
+
+
+class ProgramAdapter:
+    """The one interface between the Offloader's stages and a program.
+
+    Every adapter has ``spec``, ``gene_length``, ``alleles`` and
+    ``allele_names``, and answers ``build_evaluator()`` (the search's
+    evaluator), ``baseline_time()`` (the all-host time),
+    ``analyze_payload()`` and ``placement(genes)`` ({unit: destination}).
+    The defaults below mean "not applicable":
+
+    - ``deterministic`` — may a re-measure of the winner be held to
+      exactness (analytic evaluators: yes);
+    - ``run_fn`` — the runnable program at the spec's measured scale, or
+      None when the program has no runnable implementation;
+    - ``verify(genes)`` — ``(PcastReport | None, answer | None)``: the
+      PCAST check of the genome's placement, and the placed run's output
+      where the check ran at the measured scale;
+    - ``search_extras(genes)`` — extra search-payload entries of the
+      winner;
+    - ``scale_model()`` — the analytic model AT THE MEASURED SCALE, what
+      the fidelity and rank sections compare wall clocks against;
+    - ``realised(genes)`` — the :class:`Realised` projection, or None
+      without a runnable implementation;
+    - ``clock()`` — a wall clock of ``run_fn`` outside any pool or cache.
+
+    Mixed mode's warm start also calls ``sub_evaluator`` and
+    ``reexpress``; the spec keeps ``warm_start`` to mixed mode.
+    """
+
+    deterministic = True
+    run_fn: Any = None
+    library = None  # the block library, where blocks are matched
+    host = "cpu"  # the host's destination name
+    accel: Optional[int] = 1  # the allele of the jit path's destination
+    spec: OffloadSpec
+    gene_length: int
+    allele_names: Tuple[str, ...]
+
+    def placement(self, genes: Sequence[int]) -> Dict[str, str]:
+        raise NotImplementedError
+
+    def verify(self, genes: Sequence[int]
+               ) -> Tuple[Optional[pcast.PcastReport], Any]:
+        # the modeled adapters check the implementation at a small grid
+        # (_PCAST_RUN_FNS): nothing is timed there, so it hands back no
+        # answer
+        real = self.realised(genes)
+        if real is None:
+            return None, None
+        pair = _PCAST_RUN_FNS[self.spec.program].pair
+        return _pcast(pair, real.offloaded, self.spec), None
+
+    def search_extras(self, genes: Sequence[int]) -> Dict[str, Any]:
+        return {}
+
+    def scale_model(self) -> Optional[Callable[[Sequence[int]], float]]:
+        return None
+
+    def realised(self, genes: Sequence[int]) -> Optional[Realised]:
+        if self.run_fn is None:
+            return None
+        place = self.placement(genes).get(self.run_fn.hot_loop, self.host)
+        offloaded = place != self.host
+        if not offloaded:
+            allele, dest = 0, self.host
+        elif self.accel is None:
+            allele, dest = 1, None
+        else:
+            allele, dest = self.accel, self.allele_names[self.accel]
+        hot = self.run_fn.hot_gene
+        genes = tuple(allele if i == hot else 0
+                      for i in range(self.gene_length))
+        return Realised(genes, offloaded, dest)
+
+    def clock(self) -> ev.MeasuredEvaluator:
+        return ev.MeasuredEvaluator(self.run_fn, repeats=self.spec.repeats,
+                                    tag=self.run_fn.tag)
+
+
+class MiniappBinaryAdapter(ProgramAdapter):
     """The paper's binary CPU/GPU search under a METHODS configuration."""
 
-    kind = "miniapp-binary"
-    deterministic = True  # analytic evaluator: re-measuring is exact
+    alleles = 2
+    allele_names = ("cpu", "gpu")
 
     def __init__(self, spec: OffloadSpec,
                  hw: Optional[ev.HardwareModel] = None):
@@ -167,27 +277,30 @@ class MiniappBinaryAdapter:
         self.hw = resolve_hw(spec, hw)
         self.prog: LoopProgram = miniapps.MINIAPPS[spec.program]()
         self.method = METHODS[spec.method]
+        self.run_fn = MEASURED_RUN_FNS[spec.measured_scale].get(spec.program)
 
     @property
     def gene_length(self) -> int:
         return self.prog.gene_length
 
-    @property
-    def alleles(self) -> int:
-        return 2
-
-    @property
-    def allele_names(self) -> Tuple[str, ...]:
-        return ("cpu", "gpu")
-
-    def build_evaluator(self) -> ev.MiniappEvaluator:
+    def _evaluator_of(self, prog: LoopProgram) -> ev.MiniappEvaluator:
         return ev.MiniappEvaluator(
-            self.prog,
+            prog,
             tr.TransferMode(self.method["transfer"]),
             staged=self.method["staged"],
             hw=self.hw,
             kernels_only=self.method["kernels_only"],
         )
+
+    def build_evaluator(self) -> ev.MiniappEvaluator:
+        return self._evaluator_of(self.prog)
+
+    def scale_model(self) -> Optional[ev.MiniappEvaluator]:
+        """The analytic model at the measured scale, under the spec's
+        method configuration and modeled machine."""
+        if self.run_fn is None:
+            return None
+        return self._evaluator_of(self.run_fn.program())
 
     def baseline_time(self) -> float:
         # all loops on the CPU, priced exactly as the fig4/fig5 scripts
@@ -203,15 +316,7 @@ class MiniappBinaryAdapter:
             "gene_length": self.gene_length,
             "n_loops": len(self.prog.loops),
             "kernels_only": bool(self.method["kernels_only"]),
-            "loops": [
-                {
-                    "name": l.name,
-                    "class": l.klass.value,
-                    "directive": DIRECTIVES[l.klass],
-                    "offloadable": l.offloadable,
-                }
-                for l in self.prog.loops
-            ],
+            "loops": _loop_rows(self.prog),
         }
 
     def placement(self, genes: Sequence[int]) -> Dict[str, str]:
@@ -221,17 +326,8 @@ class MiniappBinaryAdapter:
             out[l.name] = "gpu" if g else "cpu"
         return out
 
-    def pcast_check(self, genes: Sequence[int]
-                    ) -> Optional[pcast.PcastReport]:
-        hot = RUNNABLE.get(self.prog.name)
-        if hot is None:
-            return None
-        loop_name, pair = hot
-        offloaded = self.placement(genes)[loop_name] != "cpu"
-        return _pcast(pair, offloaded, self.spec)
 
-
-class MiniappMeasuredAdapter:
+class MiniappMeasuredAdapter(MiniappBinaryAdapter):
     """Measured fidelity: the paper's REAL measurement loop — every
     candidate wall-clocked by running the miniapp's implementation, not
     priced by the analytic model.
@@ -245,55 +341,27 @@ class MiniappMeasuredAdapter:
     payload and the measurement fingerprint. The run_fn's ``cache_key``
     collapses genomes to the genes the implementation actually
     distinguishes (the hot loop), so equivalent placements share one real
-    measurement exactly as the paper's §5.2 cache intends.
-    ``model_evaluator()`` exposes the analytic model AT THE MEASURED
-    SCALE for the verify stage's predicted-vs-measured fidelity section.
-    ``run_fn``, where given, takes the place of the table's row: the
-    search, the verify re-measure and the PCAST pair all run it.
+    measurement exactly as the paper's §5.2 cache intends. ``hw`` is the
+    MODEL the fidelity section compares against (``scale_model``), never
+    used to price candidates. ``run_fn``, where given, takes the place of
+    the table's row: the search, the verify re-measure and the PCAST pair
+    all run it.
     """
 
-    kind = "miniapp-measured"
     deterministic = False  # wall clocks jitter; re-measure can't be exact
 
     def __init__(self, spec: OffloadSpec,
                  hw: Optional[ev.HardwareModel] = None, run_fn=None):
         assert spec.fidelity == "measured", spec.fidelity
-        self.spec = spec
-        self.hw = resolve_hw(spec, hw)  # the MODEL the fidelity section
-        # compares against; never used to price candidates
-        self.prog: LoopProgram = miniapps.MINIAPPS[spec.program]()
-        self.run_fn = run_fn if run_fn is not None else measured_run_fn(
-            spec.program, spec.measured_scale)
-        self.method = METHODS[spec.method]
+        super().__init__(spec, hw)
+        if run_fn is not None:
+            self.run_fn = run_fn
         self.device = ev.device_info()
-
-    @property
-    def gene_length(self) -> int:
-        return self.prog.gene_length
-
-    @property
-    def alleles(self) -> int:
-        return 2
-
-    @property
-    def allele_names(self) -> Tuple[str, ...]:
-        return ("cpu", "gpu")
 
     def build_evaluator(self) -> ev.MeasuredEvaluator:
         return ev.MeasuredEvaluator(
             self.run_fn, repeats=self.spec.repeats, tag=self.run_fn.tag,
             device=f"{self.device['platform']}:{self.device['device_kind']}",
-        )
-
-    def model_evaluator(self) -> ev.MiniappEvaluator:
-        """The analytic model at the measured scale, under the spec's
-        method configuration and modeled machine."""
-        return ev.MiniappEvaluator(
-            self.run_fn.program(),
-            tr.TransferMode(self.method["transfer"]),
-            staged=self.method["staged"],
-            hw=self.hw,
-            kernels_only=self.method["kernels_only"],
         )
 
     def baseline_time(self) -> float:
@@ -313,15 +381,7 @@ class MiniappMeasuredAdapter:
             "host": e.host,
             "device": dict(self.device),
             "repeats": self.spec.repeats,
-            "loops": [
-                {
-                    "name": l.name,
-                    "class": l.klass.value,
-                    "directive": DIRECTIVES[l.klass],
-                    "offloadable": l.offloadable,
-                }
-                for l in self.prog.loops
-            ],
+            "loops": _loop_rows(self.prog),
         }
 
     def placement(self, genes: Sequence[int]) -> Dict[str, str]:
@@ -333,18 +393,13 @@ class MiniappMeasuredAdapter:
             out[l.name] = "gpu" if int(g) else "cpu"
         return out
 
-    def pcast_check(self, genes: Sequence[int]
-                    ) -> Optional[pcast.PcastReport]:
-        return self.pcast_answer(genes)[0]
-
-    def pcast_answer(self, genes: Sequence[int]
-                     ) -> Tuple[pcast.PcastReport, Dict[str, Any]]:
+    def verify(self, genes: Sequence[int]
+               ) -> Tuple[pcast.PcastReport, Dict[str, Any]]:
         """The PCAST check of a placement at the measured scale (so it
         covers what was timed), and that placement's answer: the output
         compared against the host path's. Each of the two runs is an
         ``offload.measure`` span."""
-        loop_name = RUNNABLE[self.prog.name][0]
-        offloaded = self.placement(genes)[loop_name] != "cpu"
+        offloaded = self.realised(genes).offloaded
         with ev.DEVICE_LANE:
             with ev.measure_span():
                 ref = self.run_fn.run(False)
@@ -355,11 +410,8 @@ class MiniappMeasuredAdapter:
         return report, off
 
 
-class MiniappMixedAdapter:
+class MiniappMixedAdapter(ProgramAdapter):
     """Mixed-destination k-ary search (arXiv:2011.12431 direction)."""
-
-    kind = "miniapp-mixed"
-    deterministic = True
 
     def __init__(self, spec: OffloadSpec,
                  hw: Optional[ev.HardwareModel] = None):
@@ -411,13 +463,13 @@ class MiniappMixedAdapter:
                 "match the registry"
             )
         self.prog: LoopProgram = miniapps.MINIAPPS[spec.program]()
+        self.run_fn = MEASURED_RUN_FNS[spec.measured_scale].get(spec.program)
         self._mixed_cls = MixedEvaluator
         # function-block substitution (docs/blocks.md): with spec.blocks
         # and at least one library match, the evaluator grows one gene
         # per matched block. Zero matches fall back to the plain
-        # evaluator so the search (and its cache fingerprint) stays
-        # byte-identical to a blocks-off run.
-        self.library = None
+        # evaluator, so the search (and its cache fingerprint) is the
+        # loop-level one.
         self.matches: Tuple[Any, ...] = ()
         if spec.blocks:
             from repro.blocks import default_library, match_blocks
@@ -465,6 +517,16 @@ class MiniappMixedAdapter:
     def allele_names(self) -> Tuple[str, ...]:
         return self._evaluator.allele_names()
 
+    @property
+    def host(self) -> str:
+        return self._evaluator.dests[0].name
+
+    @property
+    def accel(self) -> Optional[int]:
+        """The first gpu/tpu-kind destination: the jit path's stand-in."""
+        return next((i for i, d in enumerate(self._evaluator.dests)
+                     if d.kind in ("gpu", "tpu")), None)
+
     def build_evaluator(self):
         return self._evaluator
 
@@ -486,11 +548,28 @@ class MiniappMixedAdapter:
         return self._mixed_cls(self.prog, tuple(subset),
                                registry=self.registry)
 
+    def scale_model(self):
+        """The scalar model at the measured scale; block-enabled genomes
+        carry block genes, so they are priced by a block evaluator over
+        the scale program (same loop structure -> same matches)."""
+        if self.run_fn is None:
+            return None
+        prog = self.run_fn.program()
+        if self.matches:
+            from repro.blocks import BlockMixedEvaluator
+
+            return BlockMixedEvaluator(
+                prog, self.spec.destinations, registry=self.registry,
+                library=self.library,
+            )
+        return self._mixed_cls(prog, self.spec.destinations,
+                               registry=self.registry)
+
     def substitutions(self, genes: Sequence[int]) -> Optional[list]:
         """Per-block decision rows for a genome (None when the run has
-        no block genome — keeps blocks-off payloads byte-identical)."""
-        fn = getattr(self._evaluator, "substitutions", None)
-        return fn(genes) if fn is not None else None
+        no block genome)."""
+        return self._evaluator.substitutions(genes) if self.matches \
+            else None
 
     def reexpress(self, genes: Sequence[int], device: str) -> Tuple[int, ...]:
         """A binary (host, device) genome re-expressed in the full k-ary
@@ -534,30 +613,23 @@ class MiniappMixedAdapter:
                     for m in self.matches
                 ],
             }
-        out["loops"] = [
-            {
-                "name": l.name,
-                "class": l.klass.value,
-                "directive": DIRECTIVES[l.klass],
-                "offloadable": l.offloadable,
-                "admissible": [
-                    n for n, d in dests.items() if d.accepts(l.klass)
-                ] if l.offloadable else [],
-            }
-            for l in self.prog.loops
-        ]
+        out["loops"] = _loop_rows(self.prog)
+        for row, l in zip(out["loops"], self.prog.loops):
+            row["admissible"] = [
+                n for n, d in dests.items() if d.accepts(l.klass)
+            ] if l.offloadable else []
         return out
 
     def placement(self, genes: Sequence[int]) -> Dict[str, str]:
         return self._evaluator.placement(genes)
 
-    def schedule_stats(self, genes: Sequence[int]) -> Dict[str, Any]:
-        """Residency pressure of a genome's transfer schedule — recorded
-        in the search payload so the report stage can state eviction and
-        streaming traffic without re-running anything."""
+    def search_extras(self, genes: Sequence[int]) -> Dict[str, Any]:
+        """The winner's residency pressure — so the report stage can
+        state eviction and streaming traffic without re-running anything
+        — and, on block-enabled runs, its substitutions."""
         bd = self._evaluator.breakdown(genes)
         s = bd.schedule
-        return {
+        out: Dict[str, Any] = {"residency": {
             "transfer_s": float(bd.transfer_s),
             "transfer_bytes": float(s.total_bytes),
             "evicted_bytes": float(s.total_evicted_bytes),
@@ -570,17 +642,11 @@ class MiniappMixedAdapter:
             },
             "oversubscribed": list(s.oversubscribed),
             "capacities": self._capacities(),
-        }
-
-    def pcast_check(self, genes: Sequence[int]
-                    ) -> Optional[pcast.PcastReport]:
-        hot = RUNNABLE.get(self.prog.name)
-        if hot is None:
-            return None
-        loop_name, pair = hot
-        host = self._evaluator.dests[0].name
-        offloaded = self.placement(genes)[loop_name] != host
-        return _pcast(pair, offloaded, self.spec)
+        }}
+        subs = self.substitutions(genes)
+        if subs is not None:
+            out["substitutions"] = subs
+        return out
 
 
 class ArchPlanEvaluator:
@@ -618,7 +684,7 @@ class ArchPlanEvaluator:
         return f"analytic-plan:{self.arch}"
 
 
-class ArchAdapter:
+class ArchAdapter(ProgramAdapter):
     """Beyond-paper: genes toggle stage-group offload in an ExecutionPlan.
 
     The default evaluator is the instant analytic one; the Offloader's
@@ -626,8 +692,8 @@ class ArchAdapter:
     AOT-compile scoring (examples/ga_arch_search.py --compiled).
     """
 
-    kind = "arch"
-    deterministic = True
+    alleles = 2
+    allele_names = ("cpu", "accel")
 
     def __init__(self, spec: OffloadSpec,
                  hw: Optional[ev.HardwareModel] = None):
@@ -641,14 +707,6 @@ class ArchAdapter:
     @property
     def gene_length(self) -> int:
         return len(self.units)
-
-    @property
-    def alleles(self) -> int:
-        return 2
-
-    @property
-    def allele_names(self) -> Tuple[str, ...]:
-        return ("cpu", "accel")
 
     def build_evaluator(self) -> ArchPlanEvaluator:
         return ArchPlanEvaluator(self.spec.arch_name)
@@ -683,12 +741,10 @@ class ArchAdapter:
             self.cfg, None, genes=tuple(genes)
         ).describe()
 
-    def pcast_check(self, genes: Sequence[int]) -> None:
-        return None  # no runnable reference pair at the plan level
-
 
 def resolve_adapter(spec: OffloadSpec,
-                    hw: Optional[ev.HardwareModel] = None, run_fn=None):
+                    hw: Optional[ev.HardwareModel] = None,
+                    run_fn=None) -> ProgramAdapter:
     """The adapter of a spec; ``run_fn`` (measured fidelity only) is the
     caller's run fn in place of ``MEASURED_RUN_FNS``'s row."""
     if run_fn is not None and spec.fidelity != "measured":

@@ -44,8 +44,7 @@ METHODS: Dict[str, Dict[str, Any]] = {
 MODES = ("binary", "mixed")
 
 # How candidates are priced, end to end (docs/fidelity.md):
-# - "modeled"    — the analytic HardwareModel/MixedEvaluator (default;
-#                  byte-identical to every pre-fidelity search);
+# - "modeled"    — the analytic HardwareModel/MixedEvaluator (default);
 # - "measured"   — real wall clocks of the runnable miniapps through
 #                  MeasuredEvaluator, one at a time, in the process that
 #                  runs the Offloader (the one that holds the chip);
@@ -78,10 +77,10 @@ _SPEC_VERSION = 1
 @dataclasses.dataclass(frozen=True)
 class GAControls:
     """Search-quality knobs (docs/observability.md), nested under
-    ``OffloadSpec.ga``. Every default keeps the search byte-identical to
-    the pre-observability pipeline: ``diversity=0.0`` never enters the
-    fitness-sharing block, and the stability/rank metrics run *after*
-    the search, in the report stage, against the same fitness cache.
+    ``OffloadSpec.ga``. No default changes the search: ``diversity=0.0``
+    never enters the fitness-sharing block, and the stability/rank
+    metrics run *after* the search, in the report stage, against the same
+    fitness cache.
     """
 
     # fitness-sharing strength (GAParams.diversity): an individual's
@@ -106,12 +105,12 @@ class GAControls:
     rank_probe: bool = False
     # asynchronous steady-state GA (GAParams.steady_state): offspring are
     # bred per free worker lane instead of waiting at the generation
-    # barrier. False = the historical generational loop, byte-identical.
+    # barrier. False = the generational loop.
     steady_state: bool = False
     # vectorized population pricing (BatchMixedEvaluator): mixed-mode
     # searches price whole populations in one numpy pass; the scalar
     # evaluator stays the verify-stage oracle and shares the same
-    # fingerprint/cache keys. False = scalar pricing, byte-identical.
+    # fingerprint/cache keys. False = scalar pricing.
     batch: bool = False
 
     def __post_init__(self):
@@ -147,10 +146,12 @@ class OffloadSpec:
     """Declarative input of one end-to-end offload search.
 
     ``population`` / ``generations`` / ``timeout_s`` default to ``None``
-    = "the budget the pre-redesign entry point used": the paper rule
+    = the mode's own budget: the paper rule
     (:meth:`GAParams.for_gene_length`) for binary searches, 24x24 with a
-    no-op timeout for mixed searches, and min(n, 10) for arch searches —
-    so a default spec reproduces the historical paths byte-identically.
+    no-op timeout for mixed searches, and min(n, 10) for arch searches.
+
+    ``to_dict`` serialises every field; ``from_dict`` fills a field an
+    older artifact lacks with its default, so older artifacts load.
     """
 
     program: str  # miniapp name, or "arch:<name>"
@@ -194,7 +195,7 @@ class OffloadSpec:
     # loop chains against the kernel library (repro.blocks) and extend
     # the genome with one gene per matched block choosing between
     # loop-level placement and library substitution per destination.
-    # Off = byte-identical to the loop-level search.
+    # Off = the loop-level search.
     blocks: bool = False
     # -- evaluation pool ---------------------------------------------------
     workers: int = 1
@@ -306,15 +307,13 @@ class OffloadSpec:
         assert self.is_arch, self.program
         return self.program.split(":", 1)[1]
 
-    # -- GA parameter resolution (parity with the pre-redesign paths) ------
+    # -- GA parameter resolution ---------------------------------------------
 
     def ga_params(self, gene_length: int, alleles: int = 2) -> ga.GAParams:
         """Concrete :class:`GAParams` for this spec at a gene length.
 
-        Unset (``None``) fields resolve to the budget the pre-redesign
-        entry points used, so the facade's searches stay byte-identical
-        to them; explicit values — including ``generations=0`` — are
-        taken as-is.
+        Unset (``None``) fields resolve to the mode's own budget;
+        explicit values — including ``generations=0`` — are taken as-is.
         """
         if self.mode == "mixed":
             return ga.GAParams(
@@ -366,19 +365,6 @@ class OffloadSpec:
     def to_dict(self) -> Dict[str, Any]:
         d = dataclasses.asdict(self)
         d["destinations"] = list(self.destinations)
-        if not self.blocks:
-            # serialized only when set: a blocks-off spec round-trips
-            # byte-identically to pre-blocks artifacts (same digest)
-            del d["blocks"]
-        if self.measured_scale == "model":
-            del d["measured_scale"]  # same rule: default-scale digests
-        # same rule for the fast-search knobs: asdict recursed into the
-        # nested GAControls, so dropping the off-state keys keeps every
-        # knobs-off spec digest identical to pre-fast-search artifacts
-        if not self.ga.steady_state:
-            del d["ga"]["steady_state"]
-        if not self.ga.batch:
-            del d["ga"]["batch"]
         d["v"] = _SPEC_VERSION
         return d
 

@@ -25,9 +25,9 @@ fault injection is built in (:class:`FaultPlan`): the test suite — and
 after a stage, or SIGKILL it mid-search at a chosen generation
 (docs/serving.md#fault-injection).
 
-Single-run parity: nothing here is imported by the pipeline; an
-`Offloader` used directly is byte-identical to PR-8 behavior
-(regression-tested in tests/test_offload_service.py).
+Nothing here is imported by the pipeline: importing this module and
+building an unused service leaves an `Offloader` run's winner, best time
+and trace digest unchanged (tested in tests/test_offload_service.py).
 """
 from __future__ import annotations
 

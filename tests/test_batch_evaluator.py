@@ -212,9 +212,11 @@ def test_steady_state_single_generation_falls_back_to_barrier():
 def test_fast_search_knobs_serialize_only_when_set():
     off = OffloadSpec(program="hetero", mode="mixed")
     d = off.to_dict()
-    assert "steady_state" not in d["ga"]
-    assert "batch" not in d["ga"]
+    # every field is serialised, the off state too, and round-trips
+    assert d["ga"]["steady_state"] is False
+    assert d["ga"]["batch"] is False
     assert OffloadSpec.from_dict(d) == off
+    assert OffloadSpec.from_json(off.to_json()) == off
 
     on = OffloadSpec(program="hetero", mode="mixed",
                      ga=GAControls(steady_state=True, batch=True))

@@ -202,10 +202,10 @@ def test_spec_blocks_is_mixed_only_and_serializes_sparsely():
         OffloadSpec(program="himeno", mode="binary", blocks=True)
     off = OffloadSpec(program="hetero", mode="mixed")
     assert not off.blocks
-    # unset => absent from the dict: pre-blocks artifacts and digests
-    # round-trip byte-identically
-    assert "blocks" not in off.to_dict()
+    # unset => serialised as False, and round-trips
+    assert off.to_dict()["blocks"] is False
     assert OffloadSpec.from_dict(off.to_dict()) == off
+    assert OffloadSpec.from_json(off.to_json()) == off
     on = OffloadSpec(program="hetero", mode="mixed", blocks=True)
     assert on.to_dict()["blocks"] is True
     assert OffloadSpec.from_dict(on.to_dict()) == on

@@ -208,7 +208,7 @@ def test_measured_adapter_resolution_and_shape():
     assert ad.gene_length == 13 and ad.alleles == 2
     e = ad.build_evaluator()
     assert isinstance(e, ev.MeasuredEvaluator) and e.repeats == 2
-    model = ad.model_evaluator()
+    model = ad.scale_model()
     # the model prediction lives at the MEASURED scale, not paper scale
     assert model.prog.gene_length == ad.gene_length
     assert model.prog.description != ad.prog.description

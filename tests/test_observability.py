@@ -374,13 +374,14 @@ def test_fast_search_knobs_thread_through_the_spec(tmp_path):
     assert ga_payload["steady_state"] is True
     spec2 = OffloadSpec.from_dict(json.loads(spec.to_json()))
     assert spec2.ga.steady_state and spec2 == spec
-    # knobs-off searches must not even carry the keys: payload and spec
-    # JSON stay byte-identical to pre-fast-search artifacts
+    # knobs-off searches record the off state, and the spec JSON
+    # round-trips with it
     base, _ = _run(tmp_path, "base", SPEC)
-    assert "steady_state" not in base.stage("search").payload["ga"]
-    assert "batch" not in base.stage("search").payload["ga"]
+    assert base.stage("search").payload["ga"]["steady_state"] is False
+    assert base.stage("search").payload["ga"]["batch"] is False
     d = json.loads(SPEC.to_json())
-    assert "steady_state" not in d["ga"] and "batch" not in d["ga"]
+    assert d["ga"]["steady_state"] is False and d["ga"]["batch"] is False
+    assert OffloadSpec.from_dict(d) == SPEC
 
 
 # ---------------------------------------------------------------------------

